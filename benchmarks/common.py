@@ -12,9 +12,12 @@ OUT = REPO / "artifacts" / "bench"
 
 
 def run_devices(code: str, n_devices: int = 8, timeout: int = 560) -> str:
-    """Run `code` in a fresh process with forced host devices; return stdout."""
+    """Run `code` in a fresh process with forced host devices; return stdout.
+    The child is pinned to the CPU backend: these are host-device figure
+    reproductions, and the parent may already hold the accelerator."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
                          text=True, timeout=timeout, cwd=str(REPO))

@@ -13,7 +13,7 @@ from .common import emit, run_devices
 
 MEASURE_CODE_TEMPLATE = r"""
 import jax, jax.numpy as jnp, numpy as np, json
-from repro.core import collectives as C  # installs repro.compat jax shims
+from repro.core import collectives as C
 from jax.sharding import PartitionSpec as P, AxisType
 from repro.core.bench import time_fn, p2p_goodput, collective_goodput
 

@@ -13,12 +13,15 @@ import traceback
 
 
 def bench_kernels():
-    """Interpret-mode kernel sanity timings + allclose (not perf — CPU)."""
+    """Kernel sanity timings + allclose; the first call includes compilation
+    (not perf).  Off a TPU the kernels run in the Pallas interpreter."""
     import numpy as np
     import jax.numpy as jnp
     from repro.kernels import ops, ref
+    from repro.kernels.interpret import interpret_mode
     from .common import emit
 
+    mode = "interpret" if interpret_mode() else "mosaic"
     rows = []
     rng = np.random.RandomState(0)
     q = jnp.array(rng.randn(2, 256, 4, 64), jnp.float32)
@@ -29,13 +32,13 @@ def bench_kernels():
     err = float(np.abs(np.asarray(out) -
                        np.asarray(ref.attention_ref(fold(q), fold(q), fold(q))
                                   .reshape(2, 4, 256, 64).transpose(0, 2, 1, 3))).max())
-    rows.append({"name": "flash_attention_interpret", "us_per_call": dt * 1e6,
+    rows.append({"name": f"flash_attention_{mode}", "us_per_call": dt * 1e6,
                  "derived": f"maxerr={err:.2e}"})
     x = jnp.array(rng.randn(64, 2048), jnp.bfloat16)
     sc = jnp.ones((2048,), jnp.bfloat16)
     t0 = time.perf_counter()
     ops.rmsnorm(x, sc)
-    rows.append({"name": "rmsnorm_interpret", "us_per_call": (time.perf_counter() - t0) * 1e6,
+    rows.append({"name": f"rmsnorm_{mode}", "us_per_call": (time.perf_counter() - t0) * 1e6,
                  "derived": ""})
     emit("kernels", rows, ["name", "us_per_call", "derived"])
     return rows
@@ -116,7 +119,6 @@ def bench_calibrate():
     artifact -> plan re-ranked from measured goodput (the paper's
     measure-then-model workflow, Sec. III-A feeding Secs. IV-VI)."""
     import jax
-    import repro.compat  # noqa: F401  (AxisType shim on older jax)
     from jax.sharding import AxisType
     from repro.core.calibrate import (CalibrationProfile, compare_to_model,
                                       plan_table_deltas, run_calibration)
@@ -230,7 +232,6 @@ def bench_overlap():
                            f"chunks={p.chunks} bucket={p.bucket_bytes >> 20}MiB"})
     if jax.device_count() >= 2:
         import time as _time
-        import repro.compat  # noqa: F401
         from jax.sharding import AxisType
         from repro.configs import get_config
         from repro.configs.base import ShapeConfig
@@ -275,7 +276,6 @@ def bench_wire():
     import numpy as np
     import jax
     import jax.numpy as jnp
-    import repro.compat  # noqa: F401
     from repro.core import overlap as ov
     from repro.core import wire as wr
     from repro.core.commplan import CommPlan
@@ -306,9 +306,9 @@ def bench_wire():
         return ov.unpack_buckets(stacked, buckets, flat)
 
     def codec(flat):
-        carrier, _, _ = bc.pack(table, flat, scale=0.5, impl="xla")
+        carrier, _, _ = bc.pack(table, flat, scale=0.5)
         carrier = jax.lax.optimization_barrier(carrier)
-        return bc.unpack(table, carrier, flat, impl="xla")
+        return bc.unpack(table, carrier, flat)
 
     from repro.launch.hlo_analysis import count_jaxpr_eqns as count
 
@@ -430,7 +430,6 @@ def bench_zero():
 
     import numpy as np
     import jax
-    import repro.compat  # noqa: F401
     from repro.core import wire as wr
     from repro.core.commplan import CommPlan
     from repro.core.costmodel import exposed_comm_time
@@ -536,7 +535,6 @@ def bench_moe():
 
     import numpy as np
     import jax
-    import repro.compat  # noqa: F401
     from repro.core import program as prg
     from repro.core import scenarios as sc
     from repro.core.commplan import CommPlan
@@ -679,7 +677,6 @@ def bench_lint():
     from pathlib import Path
 
     import jax
-    import repro.compat  # noqa: F401
     from repro.core import program as prg
     from repro.launch.lint import lint_named_programs, lint_program_on_mesh
     from .common import emit
@@ -735,7 +732,6 @@ def bench_hlolint():
     from pathlib import Path
 
     import jax
-    import repro.compat  # noqa: F401
     from repro.core import program as prg
     from repro.launch.lint import lint_named_programs, lint_program_on_mesh
     from .common import emit
@@ -802,7 +798,6 @@ def bench_faults():
     from pathlib import Path
 
     import jax
-    import repro.compat  # noqa: F401
     from repro.core.scenarios import (MESSY_SCENARIOS, check_degradation_shapes,
                                       sweep_degradation)
     from .common import emit
@@ -909,7 +904,10 @@ def bench_faults():
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
     from .figures import ALL_FIGURES
+
+    use_compile_cache()
 
     filters = [a for a in sys.argv[1:] if not a.startswith("-")]
     sections = dict(ALL_FIGURES)

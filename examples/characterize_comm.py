@@ -20,7 +20,6 @@ sys.path.insert(0, str(ROOT / "src"))
 
 def inner(n_devices: int):
     import jax
-    import repro.compat  # jax API shims before touching jax.sharding
     from jax.sharding import AxisType
 
     from repro.core.bench import print_records, write_csv
@@ -84,6 +83,7 @@ def main():
         return
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={args.devices}"
+    env["JAX_PLATFORMS"] = "cpu"  # host devices are the measured fabric
     env["PYTHONPATH"] = str(ROOT / "src") + os.pathsep + env.get("PYTHONPATH", "")
     sys.exit(subprocess.call([sys.executable, __file__, "--devices",
                               str(args.devices), "--_inner"], env=env))

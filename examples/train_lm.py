@@ -32,7 +32,6 @@ def run_moe(args):
     The DP axis doubles as the expert axis; the plan's stats show which
     alltoall algorithm the per-tier tables dispatched."""
     import jax
-    import repro.compat  # noqa: F401  (jax API shims)
     from jax.sharding import AxisType
 
     from repro.core import program as prg

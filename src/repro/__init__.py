@@ -3,6 +3,4 @@
 Reproduction of "Exploring GPU-to-GPU Communication: Insights into Supercomputer
 Interconnects" (SC'24), adapted to a TPU v5e multi-pod target.  See DESIGN.md.
 """
-from . import compat  # installs jax API shims when running on older jax
-
 __version__ = "1.0.0"

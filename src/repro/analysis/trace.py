@@ -25,6 +25,7 @@ import dataclasses
 from typing import Callable, Dict, FrozenSet, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core as jex
 
 #: canonical collective kinds a CollectiveRecord can carry
 COLLECTIVE_KINDS: FrozenSet[str] = frozenset(
@@ -43,9 +44,9 @@ def _sub_jaxprs(eqn):
     for val in eqn.params.values():
         vals = val if isinstance(val, (tuple, list)) else (val,)
         for u in vals:
-            if isinstance(u, jax.core.ClosedJaxpr):
+            if isinstance(u, jex.ClosedJaxpr):
                 yield u.jaxpr
-            elif isinstance(u, jax.core.Jaxpr):
+            elif isinstance(u, jex.Jaxpr):
                 yield u
 
 
@@ -169,7 +170,7 @@ class CollectiveTrace:
     records: Tuple[CollectiveRecord, ...]
     donate_argnums: Tuple[int, ...] = ()
     n_eqns: int = 0                # total equations (nested)
-    n_concats: int = 0             # concatenate equations (nested, unweighted)
+    n_concats: int = 0             # flat concatenates (nested, unweighted)
 
     def of_kind(self, kind: str) -> Tuple[CollectiveRecord, ...]:
         return tuple(r for r in self.records if r.kind == kind)
@@ -203,7 +204,12 @@ def trace_jaxpr(closed, donate_argnums: Sequence[int] = ()) -> CollectiveTrace:
         n_eqns += 1
         name = eqn.primitive.name
         if name == "concatenate":
-            n_concats += 1
+            # only flat (rank <= 2) operands are wire packing: leaf pieces and
+            # carrier rows.  The model's own concatenates (rope halves on
+            # (B, S, H, hd)) recur once per traced gradient, which a
+            # microbatched step traces twice
+            if eqn.outvars[0].aval.ndim <= 2:
+                n_concats += 1
         elif name in _PRIM_TO_KIND:
             records.append(_record(eqn, depth, trips))
 

@@ -1,4 +1,5 @@
-"""Fused bucket wire codec: one-kernel gradient pack/unpack + wire quantization.
+"""Bucket wire codec: gradient pack/unpack + wire quantization over a static
+address table.
 
 The explicit-DP hot path used to materialize the gradient wire format with
 O(leaves x buckets) HLO: one `concatenate` per bucket (each slicing spans out
@@ -8,32 +9,30 @@ bottom line (Obs. 1/4/5) is that exactly this kind of software overhead — not
 the interconnect — is what leaves bandwidth untapped.
 
 This module replaces that path with a *codec*: a static address table computed
-once per tree structure from `overlap.make_buckets`, plus two fused kernels:
+once per tree structure from `overlap.make_buckets`, plus
 
   * **pack** — gathers every gradient leaf into the stacked
-    `(n_buckets, bucket_elems)` carrier *and quantizes to the wire dtype in the
-    same kernel* (fp32 / bf16 / int8 + per-bucket scales).  For int8 the
-    error-feedback state (a carrier-shaped fp32 buffer) is added before
-    quantization and the new error is emitted by the same kernel, so
-    compression composes with the overlap scan schedule instead of excluding
-    it.
+    `(n_buckets, bucket_elems)` carrier and quantizes it to the wire dtype
+    (fp32 / bf16 / int8 + per-bucket scales).  For int8 the error-feedback
+    state (a carrier-shaped fp32 buffer) is added before quantization and the
+    new error is returned alongside, so compression composes with the overlap
+    scan schedule instead of excluding it.
   * **unpack** — dequantizes the reduced carrier and scatters it back into
     per-leaf fp32 arrays.
 
-Three interchangeable implementations (`impl=`):
+Both lower to XLA with O(1) `concatenate` ops regardless of leaf count (zero,
+in fact): the address table makes every leaf a single contiguous carrier
+range, so pack is one `dynamic_update_slice` per leaf into a flat buffer and
+unpack is one slice per leaf.  There is no Pallas pack/unpack: a TPU DMA slice
+must be aligned to the HBM tiling (1024 elements for a flat array), and the
+spans start wherever the previous leaf ended (a 576-element norm scale puts
+every later span off that grid), so a kernel would need in-VMEM lane rotation
+and masked stores that nothing has measured to beat XLA's own copies.
 
-  * ``"pallas"`` — the fused Pallas kernels, grid over buckets, span copies
-    unrolled from the static table (pattern: `kernels/flash_attention.py`).
-    Runs in interpret mode off-TPU so CPU CI exercises the kernel path.  A
-    production TPU deployment would move the span table to scalar prefetch
-    instead of unrolled `pl.when` branches; block shapes here keep every leaf
-    resident, which is fine for the reduced CI configs.
-  * ``"xla"`` — pure `dynamic_update_slice` / `dynamic_slice` lowering with
-    O(1) `concatenate` ops regardless of leaf count (zero, in fact): the
-    address table makes every leaf a single contiguous carrier range, so pack
-    is one `dynamic_update_slice` per leaf into a flat buffer and unpack is
-    one slice per leaf.  This is the default on CPU hosts.
-  * ``"auto"`` — ``"pallas"`` on TPU backends, ``"xla"`` elsewhere.
+  * **adamw_update_shard** — the ZeRO shard update, elementwise, with a
+    Pallas kernel (``impl="pallas"``) tiled `(n_buckets, lanes)` over the
+    shard columns, and an XLA implementation.  ``impl="auto"`` is XLA on every
+    backend until a chip measurement shows the kernel faster.
 
 Numerics: fp32 pack/unpack is exact (validated element-for-element against
 `pack_buckets`/`unpack_buckets`); bf16 is a cast on the wire; int8 uses
@@ -54,6 +53,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.overlap import Bucket, make_buckets
+from .interpret import interpret_mode
 
 # wire name -> jnp dtype on the wire (byte/sideband accounting lives in
 # core.wire.WIRE_FORMATS — the single source of truth the cost model shares)
@@ -73,7 +73,7 @@ class CodecTable:
     elements [src_lo, src_hi).  Because `make_buckets` walks leaves in a fixed
     order and splits them only at bucket boundaries, every leaf also occupies
     one *contiguous* range of the flattened carrier starting at
-    `leaf_offsets[i]` — which is what lets the XLA fallback pack with a single
+    `leaf_offsets[i]` — which is what lets pack write with a single
     `dynamic_update_slice` per leaf and unpack with a single slice per leaf.
     Zero-size leaves own no span and `leaf_offsets[i]` is -1.
     """
@@ -124,8 +124,10 @@ def make_table(sizes: Sequence[int], bucket_elems: int,
 
 
 def _resolve_impl(impl: str) -> str:
+    # "auto" is XLA on every backend: nothing picks the kernel by asking which
+    # backend it is on, and no chip run has measured it against XLA yet
     if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return "xla"
     if impl not in ("pallas", "xla"):
         raise ValueError(f"impl must be auto/pallas/xla, got {impl!r}")
     return impl
@@ -140,231 +142,69 @@ def _quantize_rows(carrier: jnp.ndarray):
     return q, s, new_err
 
 
-# ------------------------------------------------------------- XLA fallback
-def _pack_xla(table: CodecTable, flat_g, scale: float, wire: str,
-              err: Optional[jnp.ndarray]):
-    flat = jnp.zeros((table.carrier_elems,), jnp.float32)
-    for i, size in enumerate(table.sizes):
-        if size == 0:
-            continue
-        leaf = flat_g[i].reshape(-1).astype(jnp.float32)
-        flat = lax.dynamic_update_slice(flat, leaf, (table.leaf_offsets[i],))
-    carrier = (flat * scale).reshape(table.n_buckets, table.bucket_elems)
-    if wire == "int8":
-        if err is not None:
-            carrier = carrier + err
-        return _quantize_rows(carrier)
-    return carrier.astype(WIRE_DTYPES[wire]), None, err
-
-
-def _unpack_xla(table: CodecTable, carrier, like,
-                scales: Optional[jnp.ndarray]) -> List[jnp.ndarray]:
-    flat = carrier.astype(jnp.float32)
-    if scales is not None:
-        flat = flat * scales[:, None]
-    flat = flat.reshape(-1)
-    out = []
-    for i, g in enumerate(like):
-        if table.sizes[i] == 0:
-            out.append(jnp.zeros(g.shape, jnp.float32))
-            continue
-        piece = lax.dynamic_slice(flat, (table.leaf_offsets[i],),
-                                  (table.sizes[i],))
-        out.append(piece.reshape(g.shape))
-    return out
-
-
-# ------------------------------------------------------------ Pallas kernels
-def _pack_kernel(*refs, table: CodecTable, scale: float, wire: str,
-                 with_err: bool, leaf_pos):
-    k = pl.program_id(0)
-    n_in = len(leaf_pos) + (1 if with_err else 0)
-    n_out = 1 + (2 if wire == "int8" else 0)
-    leaf_refs = refs[:len(leaf_pos)]
-    err_ref = refs[len(leaf_pos)] if with_err else None
-    out_ref = refs[n_in]
-    row_scr = refs[n_in + n_out]
-    row_scr[...] = jnp.zeros_like(row_scr)  # zero-pad the final partial bucket
-    for b, row in enumerate(table.spans):
-        @pl.when(k == b)
-        def _copy(row=row):
-            for i, lo, hi, dst in row:
-                row_scr[0, dst:dst + (hi - lo)] = \
-                    leaf_refs[leaf_pos[i]][0, lo:hi].astype(jnp.float32) * scale
-    if wire == "int8":
-        scale_ref, err_out = refs[n_in + 1], refs[n_in + 2]
-        r = row_scr[...]
-        if with_err:
-            r = r + err_ref[...]
-        s = jnp.maximum(jnp.max(jnp.abs(r)), 1e-12) / 127.0
-        q = jnp.clip(jnp.round(r / s), -127, 127)
-        out_ref[...] = q.astype(jnp.int8)
-        scale_ref[0, 0] = s
-        err_out[...] = r - q * s
-    else:
-        out_ref[...] = row_scr[...].astype(out_ref.dtype)
-
-
-def _pack_pallas(table: CodecTable, flat_g, scale: float, wire: str,
-                 err: Optional[jnp.ndarray], interpret: bool):
-    nb, cap = table.n_buckets, table.bucket_elems
-    # zero-size leaves own no span: exclude them from the kernel inputs
-    live = [i for i, s in enumerate(table.sizes) if s > 0]
-    leaf_pos = {i: p for p, i in enumerate(live)}
-    inputs = [flat_g[i].reshape(1, -1) for i in live]
-    in_specs = [pl.BlockSpec((1, table.sizes[i]), lambda k: (0, 0))
-                for i in live]
-    with_err = wire == "int8" and err is not None
-    if with_err:
-        inputs.append(err)
-        in_specs.append(pl.BlockSpec((1, cap), lambda k: (k, 0)))
-    out_shape = [jax.ShapeDtypeStruct((nb, cap), WIRE_DTYPES[wire])]
-    out_specs = [pl.BlockSpec((1, cap), lambda k: (k, 0))]
-    if wire == "int8":
-        out_shape += [jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-                      jax.ShapeDtypeStruct((nb, cap), jnp.float32)]
-        out_specs += [pl.BlockSpec((1, 1), lambda k: (k, 0)),
-                      pl.BlockSpec((1, cap), lambda k: (k, 0))]
-    kernel = functools.partial(_pack_kernel, table=table, scale=scale,
-                               wire=wire, with_err=with_err, leaf_pos=leaf_pos)
-    out = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
-        out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
-        scratch_shapes=[pltpu.VMEM((1, cap), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(*inputs)
-    if wire == "int8":
-        q, s, new_err = out
-        return q, s[:, 0], new_err
-    return out, None, err
-
-
-def _unpack_kernel(*refs, table: CodecTable, dequant: bool, leaf_pos):
-    k = pl.program_id(0)
-    carrier_ref = refs[0]
-    scale_ref = refs[1] if dequant else None
-    outs = refs[2 if dequant else 1:]
-    row = carrier_ref[...].astype(jnp.float32)
-    if dequant:
-        row = row * scale_ref[0, 0]
-    for b, spans in enumerate(table.spans):
-        @pl.when(k == b)
-        def _scatter(spans=spans, row=row):
-            for i, lo, hi, dst in spans:
-                outs[leaf_pos[i]][0, lo:hi] = row[0, dst:dst + (hi - lo)]
-
-
-def _unpack_pallas(table: CodecTable, carrier, like,
-                   scales: Optional[jnp.ndarray], interpret: bool):
-    nb, cap = table.n_buckets, table.bucket_elems
-    live = [i for i, s in enumerate(table.sizes) if s > 0]
-    leaf_pos = {i: p for p, i in enumerate(live)}
-    inputs = [carrier]
-    in_specs = [pl.BlockSpec((1, cap), lambda k: (k, 0))]
-    dequant = scales is not None
-    if dequant:
-        inputs.append(scales.reshape(nb, 1))
-        in_specs.append(pl.BlockSpec((1, 1), lambda k: (k, 0)))
-    out_shape = [jax.ShapeDtypeStruct((1, table.sizes[i]), jnp.float32)
-                 for i in live]
-    out_specs = [pl.BlockSpec((1, table.sizes[i]), lambda k: (0, 0))
-                 for i in live]
-    kernel = functools.partial(_unpack_kernel, table=table, dequant=dequant,
-                               leaf_pos=leaf_pos)
-    out = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
-        out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(*inputs)
-    rows = list(out) if isinstance(out, (tuple, list)) else [out]
-    result = []
-    it = iter(rows)
-    for i, g in enumerate(like):
-        if table.sizes[i] == 0:
-            result.append(jnp.zeros(g.shape, jnp.float32))
-        else:
-            result.append(next(it).reshape(g.shape))
-    return result
-
-
 # ------------------------------------------------- fused sharded AdamW update
-def _adamw_shard_xla(g, p, m, v, clip, lr, bc1, bc2, b1, b2, eps, wd, wire):
+#: VMEM budget of one (n_buckets, lanes) fp32 block of the shard update; the
+#: kernel double-buffers 4 inputs and 3 outputs, ~4 MiB in all, well inside
+#: the 16 MiB a TPU v5e kernel may use by default
+_ADAMW_BLOCK_BYTES = 512 << 10
+
+
+def _adamw_lanes(nb: int, sh: int) -> int:
+    """Lane width of the shard update's `(nb, lanes)` blocks: the widest
+    multiple of 128 that divides `sh` within `_ADAMW_BLOCK_BYTES`, or the
+    whole row where `sh` is not a multiple of 128 (small shards only)."""
+    if sh % 128:
+        return sh
+    rows = -(-nb // 8) * 8  # VMEM pads the sublane dim to 8
+    lanes = 128
+    while sh % (2 * lanes) == 0 and rows * 2 * lanes * 4 <= _ADAMW_BLOCK_BYTES:
+        lanes *= 2
+    return lanes
+
+
+def _adamw_shard_xla(g, p, m, v, clip, lr, bc1, bc2, b1, b2, eps, wd):
     g = g * clip
     m = b1 * m + (1 - b1) * g
     v = b2 * v + (1 - b2) * g * g
     mhat = m / bc1
     vhat = v / bc2
     delta = mhat / (jnp.sqrt(vhat) + eps) + wd * p
-    p_new = p - lr * delta
-    if wire == "int8":
-        s = jnp.maximum(jnp.max(jnp.abs(p_new), axis=1), 1e-12) / 127.0
-        q = jnp.clip(jnp.round(p_new / s[:, None]), -127, 127).astype(jnp.int8)
-        return q, s, m, v
-    return p_new.astype(WIRE_DTYPES[wire]), None, m, v
+    return p - lr * delta, m, v
 
 
-def _adamw_shard_kernel(*refs, b1, b2, eps, wd, wire):
-    g_ref, p_ref, m_ref, v_ref, sc_ref = refs[:5]
-    outs = refs[5:]
-    clip, lr = sc_ref[0, 0], sc_ref[0, 1]
-    bc1, bc2 = sc_ref[0, 2], sc_ref[0, 3]
+def _adamw_shard_kernel(sc_ref, g_ref, p_ref, m_ref, v_ref, p_out, m_out,
+                        v_out, *, b1, b2, eps, wd):
+    clip, lr, bc1, bc2 = sc_ref[0], sc_ref[1], sc_ref[2], sc_ref[3]
     g = g_ref[...] * clip
     m = b1 * m_ref[...] + (1 - b1) * g
     v = b2 * v_ref[...] + (1 - b2) * g * g
     delta = (m / bc1) / (jnp.sqrt(v / bc2) + eps) + wd * p_ref[...]
-    p_new = p_ref[...] - lr * delta
-    if wire == "int8":
-        p_out, s_out, m_out, v_out = outs
-        s = jnp.maximum(jnp.max(jnp.abs(p_new)), 1e-12) / 127.0
-        q = jnp.clip(jnp.round(p_new / s), -127, 127)
-        p_out[...] = q.astype(jnp.int8)
-        s_out[0, 0] = s
-    else:
-        p_out, m_out, v_out = outs
-        p_out[...] = p_new.astype(p_out.dtype)
+    p_out[...] = (p_ref[...] - lr * delta).astype(p_out.dtype)
     m_out[...] = m
     v_out[...] = v
 
 
-def _adamw_shard_pallas(g, p, m, v, scalars, b1, b2, eps, wd, wire,
-                        interpret: bool):
+def _adamw_shard_pallas(g, p, m, v, scalars, b1, b2, eps, wd, p_dtype,
+                        interpret=None):
+    """The shard update tiled `(nb, lanes)` over the shard columns: every
+    block spans all bucket rows, so the row dim needs no 8-alignment, and the
+    lane dim is a multiple of 128 (or the whole row)."""
     nb, sh = g.shape
-    row = pl.BlockSpec((1, sh), lambda k: (k, 0))
-    in_specs = [row, row, row, row, pl.BlockSpec((1, 4), lambda k: (0, 0))]
-    out_shape = [jax.ShapeDtypeStruct((nb, sh), WIRE_DTYPES[wire])]
-    out_specs = [row]
-    if wire == "int8":
-        out_shape.append(jax.ShapeDtypeStruct((nb, 1), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1), lambda k: (k, 0)))
-    out_shape += [jax.ShapeDtypeStruct((nb, sh), jnp.float32)] * 2
-    out_specs += [row, row]
+    lanes = _adamw_lanes(nb, sh)
+    block = pl.BlockSpec((nb, lanes), lambda j: (0, j))
+    f32 = jax.ShapeDtypeStruct((nb, sh), jnp.float32)
     kernel = functools.partial(_adamw_shard_kernel, b1=b1, b2=b2, eps=eps,
-                               wd=wd, wire=wire)
-    out = pl.pallas_call(
+                               wd=wd)
+    return pl.pallas_call(
         kernel,
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        grid=(sh // lanes,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [block] * 4,
+        out_specs=[block] * 3,
+        out_shape=[jax.ShapeDtypeStruct((nb, sh), p_dtype), f32, f32],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(g, p, m, v, scalars)
-    if wire == "int8":
-        q, s, new_m, new_v = out
-        return q, s[:, 0], new_m, new_v
-    p_wire, new_m, new_v = out
-    return p_wire, None, new_m, new_v
+            dimension_semantics=("parallel",)),
+        interpret=interpret_mode(interpret),
+    )(scalars, g, p, m, v)
 
 
 def adamw_update_shard(g: jnp.ndarray, p: jnp.ndarray, m: jnp.ndarray,
@@ -386,28 +226,34 @@ def adamw_update_shard(g: jnp.ndarray, p: jnp.ndarray, m: jnp.ndarray,
     `wire` is the all-gather leg's format: fp32/bf16 cast `p_new` (scales is
     None); int8 requantizes per bucket-shard with symmetric scales — the
     sideband the gather moves is one fp32 scale per (bucket, device) shard.
+    The per-row scale needs the whole row, so the kernel (tiled over columns)
+    hands back fp32 and the requantization is the pack's `_quantize_rows`.
     Moments always stay fp32 and carrier-sharded.
     """
     if wire not in WIRE_DTYPES:
         raise ValueError(f"unknown wire format {wire!r}; "
                          f"one of {sorted(WIRE_DTYPES)}")
+    p_dtype = jnp.float32 if wire == "int8" else WIRE_DTYPES[wire]
     if _resolve_impl(impl) == "pallas":
-        scalars = jnp.stack([jnp.asarray(clip, jnp.float32),
-                             jnp.asarray(lr, jnp.float32),
-                             jnp.asarray(bc1, jnp.float32),
-                             jnp.asarray(bc2, jnp.float32)]).reshape(1, 4)
-        return _adamw_shard_pallas(g, p, m, v, scalars, b1, b2, eps,
-                                   weight_decay, wire,
-                                   interpret=jax.default_backend() != "tpu")
-    return _adamw_shard_xla(g, p, m, v, clip, lr, bc1, bc2, b1, b2, eps,
-                            weight_decay, wire)
+        scalars = jnp.stack([jnp.asarray(x, jnp.float32)
+                             for x in (clip, lr, bc1, bc2)])
+        p_new, m, v = _adamw_shard_pallas(g, p, m, v, scalars, b1, b2, eps,
+                                          weight_decay, p_dtype)
+    else:
+        p_new, m, v = _adamw_shard_xla(g, p, m, v, clip, lr, bc1, bc2, b1, b2,
+                                       eps, weight_decay)
+        p_new = p_new.astype(p_dtype)
+    if wire == "int8":
+        q, s, _ = _quantize_rows(p_new)
+        return q, s, m, v
+    return p_new, None, m, v
 
 
 # ------------------------------------------------------------------- public
 def pack(table: CodecTable, flat_g: Sequence[jnp.ndarray], *,
          scale: float = 1.0, wire: str = "fp32",
-         err: Optional[jnp.ndarray] = None, impl: str = "auto"):
-    """Fused gather + wire-quantize: leaves -> (carrier, scales, new_err).
+         err: Optional[jnp.ndarray] = None):
+    """Gather + wire-quantize: leaves -> (carrier, scales, new_err).
 
     `carrier` is `(n_buckets, bucket_elems)` in the wire dtype; the final
     partial bucket is zero-padded (zeros are the reduction identity).  `scale`
@@ -424,26 +270,42 @@ def pack(table: CodecTable, flat_g: Sequence[jnp.ndarray], *,
                          f"one of {sorted(WIRE_DTYPES)}")
     if table.n_buckets == 0:
         raise ValueError("cannot pack an empty table (no gradient elements)")
-    if _resolve_impl(impl) == "pallas":
-        return _pack_pallas(table, flat_g, scale, wire, err,
-                            interpret=jax.default_backend() != "tpu")
-    return _pack_xla(table, flat_g, scale, wire, err)
+    flat = jnp.zeros((table.carrier_elems,), jnp.float32)
+    for i, size in enumerate(table.sizes):
+        if size == 0:
+            continue
+        leaf = flat_g[i].reshape(-1).astype(jnp.float32)
+        flat = lax.dynamic_update_slice(flat, leaf, (table.leaf_offsets[i],))
+    carrier = (flat * scale).reshape(table.n_buckets, table.bucket_elems)
+    if wire == "int8":
+        if err is not None:
+            carrier = carrier + err
+        return _quantize_rows(carrier)
+    return carrier.astype(WIRE_DTYPES[wire]), None, err
 
 
 def unpack(table: CodecTable, carrier: jnp.ndarray,
            like: Sequence[jnp.ndarray],
-           scales: Optional[jnp.ndarray] = None,
-           impl: str = "auto") -> List[jnp.ndarray]:
-    """Fused dequantize + scatter: reduced carrier -> per-leaf fp32 arrays
-    shaped like `like` (inverse of `pack` up to the wire dtype's rounding).
+           scales: Optional[jnp.ndarray] = None) -> List[jnp.ndarray]:
+    """Dequantize + scatter: reduced carrier -> per-leaf fp32 arrays shaped
+    like `like` (inverse of `pack` up to the wire dtype's rounding).
     Zero-size leaves come back as fp32 zeros.  `carrier` may also be a list of
     1-D rows (the eager reduction path); it is stacked once here."""
     if not isinstance(carrier, jnp.ndarray):
         carrier = jnp.stack(list(carrier))
-    if _resolve_impl(impl) == "pallas":
-        return _unpack_pallas(table, carrier, like, scales,
-                              interpret=jax.default_backend() != "tpu")
-    return _unpack_xla(table, carrier, like, scales)
+    flat = carrier.astype(jnp.float32)
+    if scales is not None:
+        flat = flat * scales[:, None]
+    flat = flat.reshape(-1)
+    out = []
+    for i, g in enumerate(like):
+        if table.sizes[i] == 0:
+            out.append(jnp.zeros(g.shape, jnp.float32))
+            continue
+        piece = lax.dynamic_slice(flat, (table.leaf_offsets[i],),
+                                  (table.sizes[i],))
+        out.append(piece.reshape(g.shape))
+    return out
 
 
 def wire_bytes(table: CodecTable, wire: str) -> int:

@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels (shape adaptation + dispatch).
 
-`interpret` defaults to True in this CPU container; on a TPU deployment pass
-interpret=False (Mosaic lowering) — the call sites in models/ flip via
-cfg.attn_impl == "pallas".
+`interpret=None` lets `kernels.interpret.interpret_mode` decide: compiled on a
+TPU, interpreted elsewhere.  The model reaches these via cfg.attn_impl ==
+"pallas".
 """
 from __future__ import annotations
 
@@ -18,7 +18,7 @@ from . import ssd_scan as _ssd
 
 @partial(jax.jit, static_argnames=("causal", "q_block", "kv_block", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, q_block: int = 128,
-                    kv_block: int = 128, interpret: bool = True):
+                    kv_block: int = 128, interpret=None):
     """q: (B, S, H, hd); k, v: (B, S, H, hd) (kv already repeated to H heads).
     Returns (B, S, H, hd)."""
     b, s, h, hd = q.shape
@@ -30,7 +30,7 @@ def flash_attention(q, k, v, *, causal: bool = True, q_block: int = 128,
 
 
 @partial(jax.jit, static_argnames=("eps", "interpret"))
-def rmsnorm(x, scale, *, eps: float = 1e-5, interpret: bool = True):
+def rmsnorm(x, scale, *, eps: float = 1e-5, interpret=None):
     """x: (..., D)."""
     shape = x.shape
     out = _rn.rmsnorm_fwd(x.reshape(-1, shape[-1]), scale, eps=eps,
@@ -39,7 +39,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-5, interpret: bool = True):
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = True):
+def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret=None):
     """Mamba2 SSD over chunks.  x: (b, s, h, p); B, C: (b, s, 1, n) or (b, s, n)."""
     if B.ndim == 4:
         B = B[:, :, 0, :]

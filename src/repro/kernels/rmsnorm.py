@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .interpret import interpret_mode
+
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -22,7 +24,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_fwd(x, scale, *, eps: float = 1e-5, block_rows: int = 128,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret=None) -> jnp.ndarray:
     """x: (R, D); scale: (D,)."""
     r, d = x.shape
     block_rows = min(block_rows, r)
@@ -38,5 +40,5 @@ def rmsnorm_fwd(x, scale, *, eps: float = 1e-5, block_rows: int = 128,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, scale)

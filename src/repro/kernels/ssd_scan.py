@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -61,7 +63,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *, chunk: 
 
 
 def ssd_scan_fwd(x, dt, A, B, C, *, chunk: int = 128, head_block: int = 0,
-                 interpret: bool = True):
+                 interpret=None):
     """x: (b, s, h, p); dt: (b, s, h); A: (h,); B, C: (b, s, n) (single group).
     Returns y: (b, s, h, p)."""
     b, s, h, p = x.shape
@@ -87,5 +89,5 @@ def ssd_scan_fwd(x, dt, A, B, C, *, chunk: int = 128, head_block: int = 0,
         scratch_shapes=[pltpu.VMEM((hb, p, n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, dt, A, B, C)

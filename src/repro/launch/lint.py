@@ -52,7 +52,6 @@ def _dense_fixture(n_devices: int, n_leaves: int = 6, leaf_elems: int = 65):
 
 
 def _make_mesh(shape: Tuple[int, ...], names: Tuple[str, ...]):
-    import repro.compat  # noqa: F401 (make_mesh axis_types shim)
     import jax
     from jax.sharding import AxisType
 

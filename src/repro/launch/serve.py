@@ -13,11 +13,13 @@ import numpy as np
 from ..configs.base import get_config, list_configs
 from ..models.model import build_model
 from ..runtime.serve import BatchedServer, ServeConfig, throughput_report
+from .compile_cache import use_compile_cache
 from .mesh import make_host_mesh
 from .train import parse_mesh
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_configs())
     ap.add_argument("--reduced", action="store_true")

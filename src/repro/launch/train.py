@@ -8,9 +8,10 @@
       [--compress-bits {0,8,auto}] [--zero] \
       [--faults messy:0|PLAN.json] [--guard] [--straggler-action sync]
 
-On this CPU container use --reduced (full configs are exercised via the dry-run).
-The mesh string "DxM" builds (data=D, model=M) over the available devices;
-"PxDxM" adds the pod axis. Without --mesh, a best-effort host mesh is used.
+--reduced cuts the config and shape to CPU size; without it the published
+widths run (chip_smoke.py drives them on a TPU).  The mesh string "DxM" builds
+(data=D, model=M) over the available devices; "PxDxM" adds the pod axis.
+Without --mesh, a best-effort host mesh is used.
 """
 from __future__ import annotations
 
@@ -23,6 +24,7 @@ from ..core import program as prg
 from ..core.autotune import CollectivePolicy
 from ..optim import OptConfig
 from ..runtime.train import Trainer, TrainConfig
+from .compile_cache import use_compile_cache
 from .mesh import make_host_mesh, make_mesh
 
 
@@ -102,6 +104,7 @@ def resolve_step_program(args, mesh, plan):
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_configs())
     ap.add_argument("--shape", default="train_4k", choices=list(SHAPES))
@@ -273,7 +276,8 @@ def main(argv=None):
         OptConfig(peak_lr=args.lr, warmup_steps=args.warmup, decay_steps=args.steps),
         TrainConfig(steps=args.steps, microbatches=args.microbatches,
                     ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt_dir,
-                    log_every=10, straggler_threshold=args.straggler_threshold,
+                    log_every=1 if args.steps <= 10 else 10,
+                    straggler_threshold=args.straggler_threshold,
                     straggler_action=args.straggler_action,
                     explicit_dp=args.explicit_dp, dcn_axis=dcn_axis,
                     policy=policy, program=program,

@@ -22,10 +22,11 @@ The loop composes:
                                     elastic re-mesh on node loss rebuilding on
                                     the surviving device set
 
-On failure injection (tests) or real XlaRuntimeError, `run()` re-enters through
-`_build()`; data replays from the restored step.  Fatal errors (anything that
-does not look like a fabric/device fault) propagate immediately — the old
-catch-all that swallowed genuine bugs is gone.
+On failure injection (tests) or a runtime error from the device, `run()`
+re-enters through `_build()`; data replays from the restored step.  Fatal
+errors propagate immediately: anything that does not look like a fabric/device
+fault, and out-of-memory or compile/lowering failures, which a replay would
+only repeat.
 """
 from __future__ import annotations
 
@@ -52,16 +53,22 @@ from .guard import DriftGuard, GuardConfig
 _TRANSIENT_MARKERS = ("injected device failure", "injected transient",
                       "device", "communicator", "nccl", "collective",
                       "data_loss", "unavailable", "deadline", "xla runtime")
+# ...except these, which a replay would only repeat: out of device memory,
+# and a step (or one of its kernels) the compiler refused
+_FATAL_MARKERS = ("resource_exhausted", "out of memory", "compil", "lowering",
+                  "mosaic")
 
 
 def _is_transient(e: BaseException) -> bool:
-    if isinstance(e, (TransientFault, NodeLossFault,
-                      jax.errors.JaxRuntimeError)):
+    if isinstance(e, (TransientFault, NodeLossFault)):
         return True
-    if isinstance(e, RuntimeError):
-        msg = str(e).lower()
-        return any(m in msg for m in _TRANSIENT_MARKERS)
-    return False
+    if not isinstance(e, RuntimeError):  # JaxRuntimeError is one
+        return False
+    msg = str(e).lower()
+    if any(m in msg for m in _FATAL_MARKERS):
+        return False
+    return (isinstance(e, jax.errors.JaxRuntimeError)
+            or any(m in msg for m in _TRANSIENT_MARKERS))
 
 
 @dataclasses.dataclass
@@ -180,7 +187,9 @@ class Trainer:
             if ax not in (c.dp_axis, c.dcn_axis) and size > 1:
                 raise ValueError(f"explicit_dp needs a pure-DP mesh; axis {ax!r} "
                                  f"has size {size}")
-        if c.microbatches > 1 and not c.overlap:
+        # a program carries its own MicrobatchLoop, which validates that it
+        # rides the overlap schedule; the flag path is checked here
+        if c.program is None and c.microbatches > 1 and not c.overlap:
             raise ValueError("explicit-DP gradient accumulation is implemented "
                              "by the overlap schedule; pass overlap=True "
                              "(launch.train --overlap) with microbatches "

@@ -1,6 +1,10 @@
 """Test-suite bootstrap.
 
-Two jobs:
+Three jobs:
+  * run the suite on the CPU backend unless the caller chose a platform: the
+    multi-device tests force host devices, and a test process must never
+    claim an accelerator (it is set here, before anything imports JAX, and
+    `tests.helpers.run_devices` hands it to child processes);
   * make `repro` importable without external PYTHONPATH plumbing (the tier-1
     command sets PYTHONPATH=src, but IDEs / CI matrices may not);
   * provide a deterministic stand-in for `hypothesis` when it isn't installed
@@ -11,9 +15,12 @@ Two jobs:
 """
 from __future__ import annotations
 
+import os
 import random
 import sys
 from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 SRC = str(Path(__file__).resolve().parent.parent / "src")
 if SRC not in sys.path:

@@ -25,6 +25,7 @@ def count_eqns(closed, name: str = None) -> int:
 def run_devices(code: str, n_devices: int = 8, timeout: int = 420) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = os.environ.get("JAX_PLATFORMS", "cpu")
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
                          text=True, timeout=timeout, cwd=str(REPO))

@@ -12,7 +12,6 @@ import jax.numpy as jnp
 import pytest
 from jax import lax
 
-import repro.compat  # noqa: F401
 from repro.analysis import (COLLECTIVE_KINDS, FINDING_CODES, CollectiveRecord,
                             CollectiveTrace, Finding, count_eqns,
                             crosscheck_trace, expected_trace, lint_trace,
@@ -549,7 +548,6 @@ import json
 import os
 import tempfile
 
-import repro.compat
 from repro.core import program as prg
 from repro.launch.lint import lint_program_on_mesh, main
 

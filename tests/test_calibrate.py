@@ -214,7 +214,6 @@ def test_write_csv_unions_heterogeneous_fieldnames(tmp_path):
 # ------------------------------------------------------------- live (slow)
 CALIB_LIVE = r"""
 import jax
-import repro.compat
 from jax.sharding import AxisType
 from repro.core.calibrate import CalibrationProfile, plan_table_deltas, run_calibration
 from repro.core.commplan import CommPlan

@@ -34,13 +34,29 @@ RAGGED_SHAPE_SETS = [
 ]
 
 
+def _codec_fns(jit):
+    """pack/unpack called eagerly, or under `jax.jit` as the steps call them
+    (the table is static, so it rides in the closure)."""
+    if not jit:
+        return bc.pack, bc.unpack
+
+    def pack(table, flat, **kw):
+        return jax.jit(lambda f: bc.pack(table, f, **kw))(flat)
+
+    def unpack(table, carrier, like, **kw):
+        return jax.jit(lambda c: bc.unpack(table, c, like, **kw))(carrier)
+
+    return pack, unpack
+
+
 @pytest.mark.parametrize("shapes", RAGGED_SHAPE_SETS)
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("jit", [False, True])
 @pytest.mark.parametrize("reverse", [True, False])
-def test_fp32_roundtrip_matches_unfused(shapes, impl, reverse):
+def test_fp32_roundtrip_matches_unfused(shapes, jit, reverse):
     """Codec pack/unpack must be element-for-element identical to the unfused
     `overlap.pack_buckets`/`unpack_buckets` across ragged, zero-size, and
-    bucket-spanning leaves, in both bucket orders and both implementations."""
+    bucket-spanning leaves, in both bucket orders, eager and jitted."""
+    pack, unpack = _codec_fns(jit)
     rng = np.random.RandomState(0)
     flat = _leaves(rng, shapes)
     sizes = [g.size for g in flat]
@@ -48,23 +64,17 @@ def test_fp32_roundtrip_matches_unfused(shapes, impl, reverse):
         table = bc.make_table(sizes, cap, reverse=reverse)
         buckets = ov.make_buckets(sizes, cap, reverse=reverse)
         assert table.n_buckets == len(buckets)
-        if impl == "pallas" and table.n_buckets > 40:
-            # the interpret-mode kernel replays the unrolled per-bucket `when`
-            # chain at every grid step (O(n_buckets^2)) — minutes at 1000+
-            # buckets.  The xla impl covers the large-table cases; pallas
-            # keeps the sub-element/ragged coverage on the small ones.
-            continue
         if table.n_buckets == 0:
             with pytest.raises(ValueError, match="empty table"):
-                bc.pack(table, flat, impl=impl)
+                pack(table, flat)
             continue
         ref = ov.pack_buckets(flat, buckets, scale=2.0)
-        carrier, scales, _ = bc.pack(table, flat, scale=2.0, impl=impl)
+        carrier, scales, _ = pack(table, flat, scale=2.0)
         assert scales is None
         assert carrier.shape == (table.n_buckets, table.bucket_elems)
         np.testing.assert_allclose(np.asarray(carrier), np.asarray(ref),
                                    rtol=1e-6)
-        back = bc.unpack(table, carrier, flat, impl=impl)
+        back = unpack(table, carrier, flat)
         ref_back = ov.unpack_buckets(ref, buckets, flat)
         for a, b, g in zip(back, ref_back, flat):
             assert a.shape == g.shape and a.dtype == jnp.float32
@@ -78,12 +88,12 @@ def test_roundtrip_input_dtypes(dtype):
     rng = np.random.RandomState(1)
     flat = _leaves(rng, [(17,), (4, 5)], dtype)
     table = bc.make_table([g.size for g in flat], 8)
-    carrier, _, _ = bc.pack(table, flat, impl="xla")
-    back = bc.unpack(table, carrier, flat, impl="xla")
+    carrier, _, _ = bc.pack(table, flat)
+    back = bc.unpack(table, carrier, flat)
     for a, g in zip(back, flat):
         np.testing.assert_array_equal(np.asarray(a),
                                       np.asarray(g.astype(jnp.float32)))
-    c16, _, _ = bc.pack(table, flat, wire="bf16", impl="xla")
+    c16, _, _ = bc.pack(table, flat, wire="bf16")
     assert c16.dtype == jnp.bfloat16
     for a, g in zip(bc.unpack(table, c16, flat), flat):
         np.testing.assert_allclose(np.asarray(a),
@@ -91,7 +101,7 @@ def test_roundtrip_input_dtypes(dtype):
                                    rtol=1e-2, atol=1e-2)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(1, 6), st.integers(1, 64), st.integers(0, 1))
 def test_roundtrip_property(n_leaves, cap, rev):
     """Property: for random leaf sets and bucket sizes, unpack(pack(x)) == x
@@ -106,40 +116,39 @@ def test_roundtrip_property(n_leaves, cap, rev):
         return
     buckets = ov.make_buckets(sizes, cap, reverse=bool(rev))
     ref = ov.pack_buckets(flat, buckets, scale=0.5)
-    carrier, _, _ = bc.pack(table, flat, scale=0.5, impl="xla")
+    carrier, _, _ = bc.pack(table, flat, scale=0.5)
     np.testing.assert_allclose(np.asarray(carrier), np.asarray(ref), rtol=1e-6)
-    for a, g in zip(bc.unpack(table, carrier, flat, impl="xla"), flat):
+    for a, g in zip(bc.unpack(table, carrier, flat), flat):
         np.testing.assert_allclose(np.asarray(a), 0.5 * np.asarray(g),
                                    rtol=1e-5, atol=1e-7)
 
 
 # ------------------------------------------------------------- int8 + errors
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_int8_pack_error_feedback_identity(impl):
-    """The in-kernel quantization must satisfy the error-feedback identity
+@pytest.mark.parametrize("jit", [False, True])
+def test_int8_pack_error_feedback_identity(jit):
+    """The quantization must satisfy the error-feedback identity
     q * scale + new_err == packed + err exactly (that is the convergence
-    guarantee), and both implementations must agree bit-for-bit."""
+    guarantee), and eager and jitted packs must agree bit-for-bit."""
+    pack, unpack = _codec_fns(jit)
     rng = np.random.RandomState(2)
     flat = _leaves(rng, [(33,), (5, 5), (0,), (7,)])
     table = bc.make_table([g.size for g in flat], 16)
     err = jnp.asarray(rng.randn(table.n_buckets, table.bucket_elems)
                       .astype(np.float32)) * 1e-3
-    q, s, new_err = bc.pack(table, flat, scale=0.25, wire="int8", err=err,
-                            impl=impl)
+    q, s, new_err = pack(table, flat, scale=0.25, wire="int8", err=err)
     assert q.dtype == jnp.int8 and s.shape == (table.n_buckets,)
-    packed, _, _ = bc.pack(table, flat, scale=0.25, impl="xla")
+    packed, _, _ = bc.pack(table, flat, scale=0.25)
     lhs = np.asarray(q).astype(np.float32) * np.asarray(s)[:, None] \
         + np.asarray(new_err)
     np.testing.assert_allclose(lhs, np.asarray(packed + err), rtol=1e-5,
                                atol=1e-7)
-    # implementations agree exactly on the wire payload
-    q2, s2, e2 = bc.pack(table, flat, scale=0.25, wire="int8", err=err,
-                         impl="xla")
+    # eager and jitted agree exactly on the wire payload
+    q2, s2, e2 = bc.pack(table, flat, scale=0.25, wire="int8", err=err)
     np.testing.assert_array_equal(np.asarray(q), np.asarray(q2))
     np.testing.assert_allclose(np.asarray(s), np.asarray(s2), rtol=1e-7)
     np.testing.assert_allclose(np.asarray(new_err), np.asarray(e2), atol=1e-7)
     # dequantized unpack stays within one quantization step of the source
-    deq = bc.unpack(table, q, flat, scales=s, impl=impl)
+    deq = unpack(table, q, flat, scales=s)
     for a, g in zip(deq, flat):
         if g.size:
             tol = float(np.asarray(s).max())
@@ -153,7 +162,7 @@ def test_int8_all_zero_bucket_stable():
     flat = [jnp.zeros((8,), jnp.float32)]
     table = bc.make_table([8], 4)
     q, s, e = bc.pack(table, flat, wire="int8",
-                      err=jnp.zeros((2, 4), jnp.float32), impl="xla")
+                      err=jnp.zeros((2, 4), jnp.float32))
     assert np.all(np.isfinite(np.asarray(s)))
     assert np.all(np.asarray(q) == 0) and np.all(np.asarray(e) == 0.0)
 
@@ -271,7 +280,6 @@ class _ToyModel:
 
 
 def _toy_step_jaxpr(n_leaves, **kw):
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
     from repro.optim import adamw
     from repro.runtime import steps as rsteps
@@ -314,7 +322,6 @@ def test_overlap_step_single_fused_pack_and_unpack():
 # ------------------------------------------------ runtime numerics (multi-dev)
 INT8_OVERLAP = r"""
 import jax, jax.numpy as jnp, numpy as np
-import repro.compat
 from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
@@ -402,7 +409,6 @@ def test_int8_composes_with_overlap_numerics():
 def test_compress_no_longer_excludes_overlap():
     """The ValueError barring compress_bits + bucketing/overlap is gone; the
     remaining guards (bad bits, per-tensor overlap, mb without overlap) hold."""
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
     from repro.optim import adamw
     from repro.runtime import steps as rsteps
@@ -428,7 +434,6 @@ def test_compress_no_longer_excludes_overlap():
 def test_init_error_state_shapes():
     """Carrier-shaped zeros when compression rides buckets; per-leaf zeros on
     the per-tensor wire."""
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
     from repro.optim import adamw
     from repro.runtime import steps as rsteps
@@ -535,7 +540,6 @@ def test_adamw_update_shard_int8_wire():
 def _toy_zero_steps(shapes, **kw):
     """Baseline + zero step pair over a params tree with `shapes` leaves on a
     1-device mesh (collectives degenerate to identity, numerics stay real)."""
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
     from repro.optim import adamw
     from repro.runtime import steps as rsteps
@@ -581,11 +585,18 @@ def test_zero_step_bit_parity_fp32(shapes, kw):
         zp, zo, zm, ze = z(zp, zo, batch, ze)
         for k in bp:
             np.testing.assert_array_equal(np.asarray(bp[k]), np.asarray(zp[k]))
-        # satellite: the psum-combined global norm equals the replicated one
-        # (to reduction-order ulp; exact-bit equality is checked with
-        # controlled values in test_zero_step_bit_parity_active_clip)
+        # the psum-combined global norm equals the replicated one up to
+        # summation order: the baseline adds per-leaf sums of squares, ZeRO
+        # sums the padded carrier shard in one reduction.  Each fp32 sum of
+        # n terms is within (n - 1) * 2**-24 of exact (relative, the terms
+        # being squares), and the sqrt halves that, so the two norms differ
+        # by at most (n - 1) * 2**-24.  The params above stay bit-for-bit.
+        # Exact-bit norms with controlled values:
+        # test_zero_step_bit_parity_active_clip
+        n = sum(int(np.prod(s)) for s in shapes)
         np.testing.assert_allclose(np.asarray(bm["grad_norm"]),
-                                   np.asarray(zm["grad_norm"]), rtol=1e-6)
+                                   np.asarray(zm["grad_norm"]),
+                                   rtol=max(n - 1, 1) * 2.0 ** -24)
         assert int(zo["step"]) == int(bo["step"])
 
 
@@ -594,7 +605,6 @@ def test_zero_step_bit_parity_active_clip():
     sums of squares the psum-combined shard norm is bit-identical to the
     replicated norm, the clip factor *actively* rescales (gnorm >> clip_norm),
     and two steps of clipped updates stay bit-for-bit."""
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
     from repro.optim import adamw
     from repro.runtime import steps as rsteps
@@ -641,7 +651,6 @@ def test_zero_step_int8_ag_close():
 
 
 def test_zero_rejects_per_tensor():
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
     from repro.optim import adamw
     from repro.runtime import steps as rsteps
@@ -656,7 +665,6 @@ def test_zero_opt_state_shapes_and_spec():
     """Carrier-sharded m/v geometry: (n_buckets, padded) fp32, padded to a
     multiple of the shard unit; the step advertises the shard spec tag and the
     abstract state mirrors the concrete one."""
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
     from repro.optim import adamw
     from repro.runtime import steps as rsteps
@@ -687,7 +695,6 @@ def test_zero_step_dispatches_rs_ag_no_gradient_allreduce():
     reduce_scatter + all_gather through the plan and *no* gradient allreduce —
     every remaining psum in the jaxpr is scalar-only (the loss pmean and the
     clip-norm combine)."""
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
     from repro.core.autotune import CollectivePolicy
     from repro.optim import adamw

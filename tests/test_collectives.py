@@ -5,7 +5,7 @@ from .helpers import run_devices
 
 VALIDATE = r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.core import collectives as C  # installs repro.compat jax shims
+from repro.core import collectives as C
 from jax.sharding import PartitionSpec as P, AxisType
 
 mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
@@ -64,7 +64,7 @@ def test_collective_algorithms_8dev():
 
 NONPOW2 = r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.core import collectives as C  # installs repro.compat jax shims
+from repro.core import collectives as C
 from jax.sharding import PartitionSpec as P, AxisType
 mesh = jax.make_mesh((6,), ("x",), axis_types=(AxisType.Auto,))
 rng = np.random.RandomState(1)
@@ -85,7 +85,7 @@ def test_ring_family_non_power_of_two():
 
 CHUNKED = r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.core import collectives as C  # installs repro.compat jax shims
+from repro.core import collectives as C
 from repro.core.overlap import chunked_hierarchical_all_reduce
 from jax.sharding import PartitionSpec as P, AxisType
 

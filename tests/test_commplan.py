@@ -134,7 +134,6 @@ def test_legacy_entry_alias():
 BUCKETED_DP = r"""
 import math
 import jax, jax.numpy as jnp, numpy as np
-import repro.compat
 from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig

@@ -6,7 +6,6 @@ from .helpers import run_devices
 
 EXPLICIT_DP = r"""
 import jax, jax.numpy as jnp, numpy as np
-import repro.compat  # jax API shims before touching jax.sharding
 from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
@@ -57,7 +56,6 @@ def test_explicit_dp_matches_xla_spmd():
 
 RESHARD = r"""
 import jax, jax.numpy as jnp, numpy as np, tempfile
-import repro.compat  # jax API shims before touching jax.sharding
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
 
@@ -104,3 +102,28 @@ print("OK")
 @pytest.mark.slow
 def test_dryrun_compiles_reduced_configs_multipod():
     assert "OK" in run_devices(DRYRUN_SMOKE, 512, timeout=560)
+
+
+LAUNCH_MICROBATCHED = r"""
+import tempfile
+from repro.launch import train
+
+for extra in (["--overlap"], ["--zero", "--overlap"]):
+    rc = train.main(["--arch", "smollm-135m", "--reduced", "--shape",
+                     "train_4k", "--mesh", "2x1", "--microbatches", "2",
+                     "--steps", "2", "--ckpt-dir", tempfile.mkdtemp()]
+                    + extra)
+    assert rc == 0, extra
+print("LAUNCH_OK")
+"""
+
+
+def test_launch_explicit_dp_programs_with_microbatches():
+    """`launch.train --overlap/--zero --microbatches N` reaches the trainer
+    as a StepProgram with a MicrobatchLoop (the path chip_smoke.py --chips 4
+    runs at full width)."""
+    out = run_devices(LAUNCH_MICROBATCHED, 2, timeout=420)
+    assert "LAUNCH_OK" in out
+    assert out.count("program: overlap_mb2") == 1
+    assert out.count("program: zero_mb2") == 1
+    assert out.count("done: step 2") == 2

@@ -218,7 +218,6 @@ def test_guard_replan_live_multidevice():
     a lint-clean mid-run re-plan and ends with strictly fewer straggler-
     exposed steps than the oblivious trainer on the same seeded fabric."""
     out = run_devices("""
-import repro.compat  # noqa: F401
 import tempfile
 import jax
 from jax.sharding import AxisType
@@ -267,7 +266,6 @@ def test_node_loss_elastic_remesh_live():
     degree shrinks to the largest batch divisor) and finishes from the last
     checkpoint."""
     out = run_devices("""
-import repro.compat  # noqa: F401
 import tempfile
 import jax
 from jax.sharding import AxisType
@@ -297,7 +295,6 @@ def test_node_loss_without_checkpoint_or_under_zero():
     """No checkpoint -> the loss surfaces; ZeRO -> the shrink refuses (the
     carrier layout depends on the DP degree)."""
     out = run_devices("""
-import repro.compat  # noqa: F401
 import tempfile
 import jax
 from jax.sharding import AxisType

@@ -78,7 +78,6 @@ def test_moe_program_shape():
 MOE_STEP = r"""
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-import repro.compat
 from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.core import scenarios as sc

@@ -168,7 +168,6 @@ def test_overlap_rejects_per_tensor_bucketing():
     """overlap=True with an explicit bucket_bytes=0 (documented per-tensor
     mode) must refuse, not silently re-bucket."""
     import jax
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
     from repro.optim import adamw
     from repro.runtime import steps as rsteps
@@ -182,7 +181,6 @@ def test_overlap_rejects_per_tensor_bucketing():
 # ------------------------------------------------------- runtime (multi-dev)
 OVERLAP_STEP = r"""
 import jax, jax.numpy as jnp, numpy as np
-import repro.compat  # jax API shims before touching jax.sharding
 from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
@@ -278,7 +276,6 @@ def test_overlap_step_schedule_and_numerics():
 
 INT8_WIRE = r"""
 import jax, jax.numpy as jnp
-import repro.compat
 from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
@@ -395,9 +392,7 @@ def test_exposed_comm_time_zero_schedule():
 # ------------------------------------------------------ ZeRO runtime (multi-dev)
 ZERO_STEP = r"""
 import jax, jax.numpy as jnp, numpy as np
-import repro.compat
 from jax.sharding import AxisType, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.core import overlap as ov
@@ -412,8 +407,8 @@ row = jnp.arange(4 * 6, dtype=jnp.float32)
 def rt(x):
     shard = ov.two_tier_reduce_scatter(x, "data")
     return ov.two_tier_all_gather(shard, "data")
-back = shard_map(rt, mesh=mesh, in_specs=P(), out_specs=P(),
-                 check_rep=False)(row)
+back = jax.shard_map(rt, mesh=mesh, in_specs=P(), out_specs=P(),
+                 check_vma=False)(row)
 np.testing.assert_array_equal(np.asarray(back), 4.0 * np.asarray(row))
 print("rt flat ok")
 
@@ -422,8 +417,8 @@ row2 = jnp.arange(2 * 2 * 3 * 2, dtype=jnp.float32)  # 2 chunks * 4 dev * 3
 def rt2(x):
     shard = ov.two_tier_reduce_scatter(x, "data", "pod", n_chunks=2)
     return ov.two_tier_all_gather(shard, "data", "pod", n_chunks=2)
-back2 = shard_map(rt2, mesh=mesh2, in_specs=P(), out_specs=P(),
-                  check_rep=False)(row2)
+back2 = jax.shard_map(rt2, mesh=mesh2, in_specs=P(), out_specs=P(),
+                  check_vma=False)(row2)
 np.testing.assert_array_equal(np.asarray(back2), 4.0 * np.asarray(row2))
 print("rt hier ok")
 
@@ -434,8 +429,8 @@ def qag(x):
     q = jnp.clip(jnp.round(shard / s), -127, 127).astype(jnp.int8)
     full = ov.quantized_all_gather(q, s, "data")
     return jax.lax.all_gather(full, "data")  # (4, N): one row per device
-rows = shard_map(qag, mesh=mesh, in_specs=P(), out_specs=P(),
-                 check_rep=False)(row)
+rows = jax.shard_map(qag, mesh=mesh, in_specs=P(), out_specs=P(),
+                 check_vma=False)(row)
 for r in range(1, 4):
     np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(rows[r]))
 print("qag replicated ok")

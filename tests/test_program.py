@@ -186,7 +186,6 @@ def test_resolve_step_program_flags():
 # ----------------------------------------------- bit-parity matrix (multi-dev)
 PARITY = r"""
 import jax, jax.numpy as jnp, numpy as np
-import repro.compat
 from jax.sharding import AxisType
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig

@@ -165,7 +165,6 @@ def test_explicit_dp_jit_cache_keyed_on_tree_structure():
     """The jitted shard_map step must not reuse the first call's specs for a
     call with a different pytree structure (stale-spec regression)."""
     import jax
-    import repro.compat  # noqa: F401  (AxisType shim)
     from jax.sharding import AxisType
     from repro.runtime import steps as rsteps
 
@@ -303,10 +302,38 @@ def test_recovery_fatal_error_propagates_immediately(tmp_path):
     assert tr.retry_log == []
 
 
+@pytest.mark.parametrize("msg", [
+    "RESOURCE_EXHAUSTED: Out of memory while trying to allocate 12.50G",
+    "INTERNAL: Mosaic failed to compile TPU kernel: Slice shape along "
+    "dimension 0 must be aligned to tiling (1024)",
+])
+def test_recovery_device_oom_and_compile_errors_are_fatal(tmp_path, msg):
+    """A device runtime error is restored and replayed, except out of memory
+    and a refused compile: a replay would only repeat them, so they stop the
+    run at once."""
+    from repro.runtime.train import _is_transient
+
+    assert _is_transient(jax.errors.JaxRuntimeError("UNAVAILABLE: link down"))
+    cfg = get_config("smollm-135m").reduced()
+    tr = Trainer(cfg, SHAPE, adamw.OptConfig(),
+                 TrainConfig(steps=6, ckpt_every=2, ckpt_async=False,
+                             ckpt_dir=str(tmp_path), log_every=100))
+    orig = tr.step_fn
+
+    def failing(params, opt_state, batch):
+        if int(opt_state["step"]) == 4:
+            raise jax.errors.JaxRuntimeError(msg)
+        return orig(params, opt_state, batch)
+
+    tr.step_fn = failing
+    with pytest.raises(jax.errors.JaxRuntimeError, match=msg.split(":")[0]):
+        tr.run()
+    assert tr.retry_log == []
+
+
 def test_straggler_skip_reverts_step(tmp_path):
     """'skip' drops the straggler step's update: the run records the skips
     and the final state is reachable without them (loss stays finite)."""
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
     from repro.core.faults import FaultEvent, FaultPlan
 
@@ -344,7 +371,6 @@ def test_mid_run_plan_swap_bit_parity(tmp_path):
     """_swap_policy on the fp32 wire is numerically transparent: checkpoint at
     6, swap the policy, resume to 12 — bitwise the same losses as an
     uninterrupted 12-step run."""
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
     from repro.core.autotune import CollectivePolicy
 
@@ -382,7 +408,6 @@ def test_trainer_zero_save_restore_and_cross_mode(tmp_path):
     """End-to-end ZeRO trainer: carrier-shaped opt state, checkpoint carries
     the shard spec, resume replays deterministically, and restoring across
     zero<->replicated trainer modes raises instead of misreading m/v."""
-    import repro.compat  # noqa: F401
     from jax.sharding import AxisType
 
     cfg = get_config("smollm-135m").reduced()
@@ -419,3 +444,23 @@ def test_trainer_zero_save_restore_and_cross_mode(tmp_path):
     make(tmp_path / "r", 4).run()
     with pytest.raises(ValueError, match="replicated checkpoint"):
         make(tmp_path / "r", 4, zero=True).restore()
+
+
+def test_compile_cache_follows_env_else_checkout(monkeypatch):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR when set
+    (nothing is set in code), else one fixed directory in the checkout."""
+    from pathlib import Path
+    from repro.launch.compile_cache import REPO_CACHE, use_compile_cache
+
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE)
+        assert REPO_CACHE == Path(__file__).resolve().parents[1] / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

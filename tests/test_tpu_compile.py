@@ -1,0 +1,113 @@
+"""Compile-only checks for a described TPU v5e: the main path's kernels and
+the gradient codec at smollm-135m's published widths.
+
+Nothing runs: each test lowers and compiles for a chip that is described, not
+attached, so a kernel the TPU compiler would refuse (block shapes off the
+(8, 128) tiling, more VMEM than a kernel may use) fails here with no chip.
+The kernels are built with ``interpret=False``, which is what a TPU process
+resolves to.  The topology is described inside a fixture, so only the worker
+that runs this file loads the TPU compiler.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core.commplan import DEFAULT_BUCKET_BYTES
+from repro.kernels import bucket_codec as bc
+from repro.kernels import ops
+from repro.models import build_model
+
+#: the ZeRO shard geometry of the four-chip explicit-DP step
+N_DP = 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def smollm_leaves():
+    """smollm-135m's parameter leaves (full width, shapes only) and the codec
+    table at the plan's default bucket size."""
+    model = build_model(get_config("smollm-135m"))
+    leaves = jax.tree.leaves(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    table = bc.make_table([x.size for x in leaves], DEFAULT_BUCKET_BYTES // 4)
+    return leaves, table
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _hlo(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("wire", ["fp32", "int8"])
+def test_codec_pack_compiles_full_width(one_chip, smollm_leaves, wire):
+    """pack lowers to XLA copies (no Pallas kernel, see bucket_codec)."""
+    leaves, table = smollm_leaves
+    assert max(x.size for x in leaves) > 28_000_000  # the tied embedding
+    flat = [_spec(x.shape, jnp.float32, one_chip) for x in leaves]
+    err = _spec((table.n_buckets, table.bucket_elems), jnp.float32, one_chip)
+    text = _hlo(lambda f, e: bc.pack(table, f, scale=0.25, wire=wire, err=e),
+                flat, err)
+    assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("wire", ["fp32", "int8"])
+def test_codec_unpack_compiles_full_width(one_chip, smollm_leaves, wire):
+    leaves, table = smollm_leaves
+    like = [_spec(x.shape, jnp.float32, one_chip) for x in leaves]
+    carrier = _spec((table.n_buckets, table.bucket_elems),
+                    bc.WIRE_DTYPES[wire], one_chip)
+    scales = (_spec((table.n_buckets,), jnp.float32, one_chip)
+              if wire == "int8" else None)
+    text = _hlo(lambda c, s: bc.unpack(table, c, like, scales=s),
+                carrier, scales)
+    assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("n_dp", [1, N_DP])
+@pytest.mark.parametrize("p_dtype", [jnp.float32, jnp.bfloat16])
+def test_adamw_shard_kernel_compiles_full_width(one_chip, smollm_leaves,
+                                                n_dp, p_dtype):
+    """The ZeRO shard update kernel over smollm's carrier shard: every bucket
+    row, the shard's columns (a whole row on one chip, a quarter on four)."""
+    _, table = smollm_leaves
+    shape = (table.n_buckets, table.bucket_elems // n_dp)
+    g, p, m, v = (_spec(shape, jnp.float32, one_chip) for _ in range(4))
+    scalars = _spec((4,), jnp.float32, one_chip)
+    text = _hlo(lambda *a: bc._adamw_shard_pallas(
+        *a, b1=0.9, b2=0.95, eps=1e-8, wd=0.1, p_dtype=p_dtype,
+        interpret=False), g, p, m, v, scalars)
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_fwd_compiles_smollm_width(one_chip):
+    """The flash-attention forward at smollm-135m's train_4k widths (S=4096,
+    9 heads of 64) in bf16."""
+    cfg = get_config("smollm-135m")
+    hd = cfg.head_dim
+    assert (cfg.n_heads, hd) == (9, 64)
+    q, k, v = (_spec((1, 4096, cfg.n_heads, hd), jnp.bfloat16, one_chip)
+               for _ in range(3))
+    text = _hlo(lambda q, k, v: ops.flash_attention(q, k, v, interpret=False),
+                q, k, v)
+    assert "tpu_custom_call" in text
