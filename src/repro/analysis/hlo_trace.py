@@ -100,6 +100,12 @@ DOT_RE = re.compile(
     r".*?lhs_contracting_dims=\{([\d,]*)\}")
 FUSED_PREFIXES = ("fused_computation", "wrapped_", "add.", "add_", "max.",
                   "min.", "region_", "and.", "or.")
+MODULE_RE = re.compile(r"^HloModule\s+([\w.\-]+)")
+# an instruction and, where its metadata has one, its op_name (escaped
+# quotes allowed)
+OP_NAME_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=(?:.*?\bmetadata=\{[^\n]*?"
+    r"\bop_name=\"((?:[^\"\\]|\\.)*)\")?", re.M)
 
 #: payloads below this are sideband/control traffic (mirrors
 #: ``analysis.expect.WIDE_BYTES``; duplicated literal avoided via import there)
@@ -233,6 +239,20 @@ def split_computations(hlo_text: str) -> Dict[str, List[str]]:
     if entry_name is not None:
         comps["__entry__"] = comps[entry_name]
     return comps
+
+
+def module_name(hlo_text: str) -> str:
+    """``jit_step`` of ``HloModule jit_step, ...`` (what a profile names
+    the module's runs, before its ``(fingerprint)``)."""
+    m = MODULE_RE.match(hlo_text)
+    return m.group(1) if m else ""
+
+
+def op_names(hlo_text: str) -> Dict[str, str]:
+    """Instruction name -> the ``op_name`` of its metadata (the jaxpr path,
+    named scopes included), ``""`` for an instruction without one (a copy or
+    layout change the compiler added)."""
+    return {m.group(1): m.group(2) or "" for m in OP_NAME_RE.finditer(hlo_text)}
 
 
 def trip_count(cond_lines: List[str]) -> int:

@@ -7,12 +7,14 @@ from __future__ import annotations
 
 import argparse
 import sys
+import time
 
 import numpy as np
 
 from ..configs.base import get_config, list_configs
 from ..models.model import build_model
-from ..runtime.serve import BatchedServer, ServeConfig, throughput_report
+from .. import telemetry
+from ..runtime.serve import BatchedServer, ServeConfig
 from .compile_cache import use_compile_cache
 from .mesh import make_host_mesh
 from .train import parse_mesh
@@ -41,10 +43,19 @@ def main(argv=None):
     server = BatchedServer(cfg, max_seq=max_seq, batch_size=args.batch, mesh=mesh)
     if args.seq_axes:
         server.model = build_model(cfg, mesh, seq_axes=tuple(args.seq_axes.split(",")))
-    rep = throughput_report(server, prompt_len=args.prompt_len,
-                            new_tokens=args.new_tokens)
-    print(f"{cfg.name}: {rep['tokens_per_s']:.1f} tok/s "
-          f"(batch {rep['batch']}, {rep['new_tokens']} new, {rep['wall_s']:.1f}s)")
+    rng = np.random.RandomState(0)
+    shape = (args.batch, args.prompt_len) + \
+        ((cfg.n_codebooks,) if cfg.n_codebooks else ())
+    prompts = rng.randint(0, cfg.vocab, shape).astype(np.int32)
+    sc = ServeConfig(max_new_tokens=args.new_tokens)
+    server.generate(prompts, sc)               # warm-up: compiles prefill and decode
+    built = telemetry.COMPILES.total
+    t0 = time.perf_counter()
+    out = server.generate(prompts, sc)
+    wall = time.perf_counter() - t0
+    print(f"{cfg.name}: {out.shape[0] * out.shape[1] / wall:.1f} tok/s "
+          f"(batch {out.shape[0]}, {out.shape[1]} new, {wall:.2f}s, "
+          f"{telemetry.COMPILES.total - built} compiles)")
     return 0
 
 

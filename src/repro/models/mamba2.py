@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from .. import telemetry
 from .layers import rms_norm
 from .sharding import Sharder
 
@@ -146,6 +147,7 @@ def _causal_conv(xBC, w, bias, conv_state=None):
     return jax.nn.silu(out).astype(xBC.dtype), pad[:, -(K - 1):, :] if K > 1 else None
 
 
+@telemetry.scope("mixer")
 def mamba_block(x, lp, cfg: ModelConfig, shd: Optional[Sharder],
                 state: Optional[Dict] = None):
     """One Mamba2 block.  x: (B, S, D).  state (decode): {"conv": (B,K-1,Cdim),

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from .. import telemetry
 from .layers import apply_rope, attention, decode_attention, mlp, rms_norm
 from .sharding import Sharder
 
@@ -145,6 +146,7 @@ def _layer_slice(lyr, i):
     return jax.tree.map(lambda a: a[i], lyr)
 
 
+@telemetry.scope("attention")
 def attn_block(x, lp, cfg: ModelConfig, shd: Sharder, positions,
                kv: Optional[Tuple] = None, pos=None):
     """Pre-norm attention block.  kv=(k_cache, v_cache) for decode (S-sharded)."""
@@ -186,6 +188,7 @@ def attn_block(x, lp, cfg: ModelConfig, shd: Sharder, positions,
     return o, new_kv
 
 
+@telemetry.scope("mlp")
 def ffn_block(x, lp, cfg: ModelConfig, shd: Sharder):
     h = rms_norm(x, lp["ln2"], fast=cfg.fast_norm)
     if cfg.family == "moe":
@@ -221,6 +224,7 @@ def embed_tokens(params, tokens, cfg: ModelConfig):
     return params["emb"][tokens]                   # (B, S, D)
 
 
+@telemetry.scope("head")
 def unembed(params, x, cfg: ModelConfig, shd: Sharder):
     head = params["emb"] if cfg.tie_embeddings else params["head"]
     if cfg.n_codebooks:
@@ -270,6 +274,7 @@ def forward_with_cache(params, x, cfg: ModelConfig, shd: Sharder, positions,
 
 
 # ------------------------------------------------------------------- losses
+@telemetry.scope("head")
 def cross_entropy(logits, targets, mask=None):
     """CE that stays sharded over the vocab dim: the gold logit is extracted with
     a masked reduction (partial + psum) instead of take_along_axis, which would

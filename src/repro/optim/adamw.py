@@ -11,6 +11,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
+
 
 @dataclasses.dataclass(frozen=True)
 class OptConfig:
@@ -64,6 +66,7 @@ def global_norm(tree) -> jnp.ndarray:
     return jnp.sqrt(sum(jnp.sum(jnp.square(l.astype(jnp.float32))) for l in leaves))
 
 
+@telemetry.scope("optimizer")
 def apply_updates(params, grads, state, cfg: OptConfig) -> Tuple[Any, Dict[str, Any], Dict[str, jnp.ndarray]]:
     step = state["step"] + 1
     gnorm = global_norm(grads)

@@ -9,7 +9,7 @@ constraints; this loop adds the serving-level bookkeeping.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +17,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig, ShapeConfig
 from ..models.model import build_model
-from . import steps as rsteps
+from .. import telemetry
 
 
 @dataclasses.dataclass
@@ -38,6 +38,10 @@ class BatchedServer:
             self.model.init(jax.random.PRNGKey(0))
         self._prefill = jax.jit(self.model.prefill)
         self._decode = jax.jit(self.model.decode)
+        # kept apart from the two above, which a caller may wrap
+        self._lower = {"serve.prefill": self._prefill.lower,
+                       "serve.decode": self._decode.lower}
+        self._noted = set()     # prompt shapes whose programs are noted
 
     def generate(self, prompts: np.ndarray, serve: Optional[ServeConfig] = None) -> np.ndarray:
         """prompts: (B, P) int32 (audio: (B, P, nq)).  Returns generated ids
@@ -45,22 +49,37 @@ class BatchedServer:
         serve = serve or ServeConfig()
         B = prompts.shape[0]
         P = prompts.shape[1]
-        cache = self.model.init_cache(self.shape, batch_size=B)
-        logits, cache = self._prefill(self.params, {"tokens": jnp.asarray(prompts)}, cache)
+        note = prompts.shape not in self._noted    # once per program
+        self._noted.add(prompts.shape)
+        with telemetry.span("serve.prefill"):
+            cache = self.model.init_cache(self.shape, batch_size=B)
+            batch = {"tokens": jnp.asarray(prompts)}
+            if note:
+                telemetry.note_program("serve.prefill", self._lower["serve.prefill"],
+                                       self.params, batch, cache)
+            logits, cache = self._prefill(self.params, batch, cache)
         key = jax.random.PRNGKey(serve.seed)
         outs = []
         done = np.zeros((B,), bool)
-        tok = self._sample(logits, serve, key)
+        with telemetry.span("serve.sample"):
+            tok = self._sample(logits, serve, key)
         for t in range(serve.max_new_tokens):
-            outs.append(np.asarray(tok))
+            with telemetry.span("serve.readback"):
+                host_tok = np.asarray(tok)
+            outs.append(host_tok)
             if serve.eos_id >= 0:
-                done |= (np.asarray(tok).reshape(B, -1)[:, 0] == serve.eos_id)
+                done |= (host_tok.reshape(B, -1)[:, 0] == serve.eos_id)
                 if done.all():
                     break
-            logits, cache = self._decode(self.params, cache, tok,
-                                         jnp.array(P + t, jnp.int32))
-            key, sub = jax.random.split(key)
-            tok = self._sample(logits, serve, sub)
+            with telemetry.span("serve.decode"):
+                pos = jnp.array(P + t, jnp.int32)
+                if note and t == 0:
+                    telemetry.note_program("serve.decode", self._lower["serve.decode"],
+                                           self.params, cache, tok, pos)
+                logits, cache = self._decode(self.params, cache, tok, pos)
+            with telemetry.span("serve.sample"):
+                key, sub = jax.random.split(key)
+                tok = self._sample(logits, serve, sub)
         return np.stack(outs, axis=1)
 
     def _sample(self, logits, serve: ServeConfig, key):
@@ -68,20 +87,3 @@ class BatchedServer:
         if serve.temperature <= 0.0:
             return jnp.argmax(lg, axis=-1).astype(jnp.int32)
         return jax.random.categorical(key, lg / serve.temperature, axis=-1).astype(jnp.int32)
-
-
-def throughput_report(server: BatchedServer, prompt_len: int = 32,
-                      new_tokens: int = 16) -> dict:
-    """Tokens/s for one batch (benchmark harness hook)."""
-    import time
-    B = server.shape.global_batch
-    rng = np.random.RandomState(0)
-    if server.cfg.n_codebooks:
-        prompts = rng.randint(0, server.cfg.vocab, (B, prompt_len, server.cfg.n_codebooks)).astype(np.int32)
-    else:
-        prompts = rng.randint(0, server.cfg.vocab, (B, prompt_len)).astype(np.int32)
-    t0 = time.perf_counter()
-    out = server.generate(prompts, ServeConfig(max_new_tokens=new_tokens))
-    dt = time.perf_counter() - t0
-    return {"batch": B, "new_tokens": int(out.shape[1]),
-            "tokens_per_s": B * out.shape[1] / dt, "wall_s": dt}

@@ -26,6 +26,7 @@ from ..core.autotune import CollectivePolicy
 from ..models.model import Model
 from ..models.sharding import Sharder, tree_shardings, tree_shardings_shaped
 from ..optim import adamw
+from .. import telemetry
 
 
 @dataclasses.dataclass
@@ -60,13 +61,16 @@ def build_train_step(model: Model, opt: adamw.OptConfig,
             def acc_body(carry, b):
                 gsum, lsum = carry
                 loss, grads = jax.value_and_grad(model.loss)(params, b)
-                gsum = jax.tree.map(lambda a, g: a + g.astype(jnp.float32), gsum, grads)
-                return (gsum, lsum + loss), None
+                with telemetry.scope("grad_accum"):
+                    gsum = jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
+                                        gsum, grads)
+                    return (gsum, lsum + loss), None
 
             zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
             (gsum, lsum), _ = jax.lax.scan(acc_body, (zeros, 0.0), mb)
-            grads = jax.tree.map(lambda g: g / microbatches, gsum)
-            loss = lsum / microbatches
+            with telemetry.scope("grad_accum"):
+                grads = jax.tree.map(lambda g: g / microbatches, gsum)
+                loss = lsum / microbatches
         else:
             loss, grads = jax.value_and_grad(model.loss)(params, batch)
         params, opt_state, metrics = adamw.apply_updates(params, grads, opt_state, opt)
@@ -336,6 +340,7 @@ def build_explicit_dp_step(model: Model, opt: adamw.OptConfig, mesh, axis: str =
             # same canonical-materialization barrier as the allreduce paths
             return loss, jax.lax.optimization_barrier(flat)
 
+        @telemetry.scope("exchange")
         def pack_pad(flat):
             carrier, _, _ = codec.pack(table, flat, scale=inv)
             return pad_cols(carrier, padded)
@@ -343,12 +348,13 @@ def build_explicit_dp_step(model: Model, opt: adamw.OptConfig, mesh, axis: str =
         if microbatches == 1:
             loss, flat_g = grads_of(batch)
             carrier = pack_pad(flat_g)
-            if overlap:
-                # scan-carried RS stream: one bucket's reduce-scatter in
-                # flight at a time, in backward materialization order
-                g_shard = ov.scan_bucket_reduce(carrier, zero_rs)
-            else:
-                g_shard = jnp.stack([zero_rs(carrier[k]) for k in range(nb)])
+            with telemetry.scope("exchange"):
+                if overlap:
+                    # scan-carried RS stream: one bucket's reduce-scatter in
+                    # flight at a time, in backward materialization order
+                    g_shard = ov.scan_bucket_reduce(carrier, zero_rs)
+                else:
+                    g_shard = jnp.stack([zero_rs(carrier[k]) for k in range(nb)])
         else:
             mb = _microbatch(batch, microbatches)
             mb0 = jax.tree.map(lambda a: a[0], mb)
@@ -361,54 +367,62 @@ def build_explicit_dp_step(model: Model, opt: adamw.OptConfig, mesh, axis: str =
                 # previous microbatch's reduce-scatters are issued FIRST (no
                 # dependency on this backward) so they overlap it; shards are
                 # accumulated — 1/n of the accumulator an all-reduce carries
-                red = jnp.stack([zero_rs(pending[k]) for k in range(nb)])
+                with telemetry.scope("exchange"):
+                    red = jnp.stack([zero_rs(pending[k]) for k in range(nb)])
                 loss, flat = grads_of(b)
-                return (acc + red, pack_pad(flat), lsum + loss), None
+                with telemetry.scope("grad_accum"):
+                    acc, lsum = acc + red, lsum + loss
+                return (acc, pack_pad(flat), lsum), None
 
             init = (jnp.zeros((nb, shard_elems), jnp.float32), pending0,
                     loss0)
             (acc, pending, lsum), _ = jax.lax.scan(body, init, rest)
-            final = jnp.stack([zero_rs(pending[k]) for k in range(nb)])
-            g_shard = acc + final
-            loss = lsum / microbatches
-        loss = jax.lax.pmean(loss, loss_axes)
+            with telemetry.scope("exchange"):
+                final = jnp.stack([zero_rs(pending[k]) for k in range(nb)])
+            with telemetry.scope("grad_accum"):
+                g_shard = acc + final
+                loss = lsum / microbatches
+        with telemetry.scope("exchange"):
+            loss = jax.lax.pmean(loss, loss_axes)
 
-        # exact global-norm clipping: the per-shard sum of squares is
-        # psum-combined over the dp axes before the clip factor forms.  This
-        # is also the schedule's semantic barrier — no shard may update until
-        # every bucket's reduce-scatter has landed.
-        gsq = jax.lax.psum(jnp.sum(jnp.square(g_shard)), loss_axes)
-        gnorm = jnp.sqrt(gsq)
-        clip = jnp.minimum(1.0, opt.clip_norm / (gnorm + 1e-9))
-        bc1 = 1 - opt.b1 ** step_no.astype(jnp.float32)
-        bc2 = 1 - opt.b2 ** step_no.astype(jnp.float32)
+        with telemetry.scope("optimizer"):
+            # exact global-norm clipping: the per-shard sum of squares is
+            # psum-combined over the dp axes before the clip factor forms.  This
+            # is also the schedule's semantic barrier — no shard may update until
+            # every bucket's reduce-scatter has landed.
+            gsq = jax.lax.psum(jnp.sum(jnp.square(g_shard)), loss_axes)
+            gnorm = jnp.sqrt(gsq)
+            clip = jnp.minimum(1.0, opt.clip_norm / (gnorm + 1e-9))
+            bc1 = 1 - opt.b1 ** step_no.astype(jnp.float32)
+            bc2 = 1 - opt.b2 ** step_no.astype(jnp.float32)
 
-        # params ride the same codec: pack (casts to fp32), pad, slice this
-        # device's shard-major blocks out of each chunk
-        p_carrier = pad_cols(codec.pack(table, flat_p)[0], padded)
-        ix = jax.lax.axis_index(axis) * n_dcn + (
-            jax.lax.axis_index(dcn_axis) if dcn_axis is not None else 0)
-        sub = shard_elems // zero_chunks
-        p_shard = jax.lax.dynamic_slice(
-            p_carrier.reshape(nb, zero_chunks, padded // zero_chunks),
-            (0, 0, ix * sub), (nb, zero_chunks, sub)).reshape(nb, shard_elems)
+            # params ride the same codec: pack (casts to fp32), pad, slice this
+            # device's shard-major blocks out of each chunk
+            p_carrier = pad_cols(codec.pack(table, flat_p)[0], padded)
+            ix = jax.lax.axis_index(axis) * n_dcn + (
+                jax.lax.axis_index(dcn_axis) if dcn_axis is not None else 0)
+            sub = shard_elems // zero_chunks
+            p_shard = jax.lax.dynamic_slice(
+                p_carrier.reshape(nb, zero_chunks, padded // zero_chunks),
+                (0, 0, ix * sub), (nb, zero_chunks, sub)).reshape(nb, shard_elems)
 
-        p_wire, p_scales, new_m, new_v = codec.adamw_update_shard(
-            g_shard, p_shard, opt_state["m"], opt_state["v"],
-            clip=clip, lr=lr, bc1=bc1, bc2=bc2, b1=opt.b1, b2=opt.b2,
-            eps=opt.eps, weight_decay=opt.weight_decay, wire=zero_wire)
+            p_wire, p_scales, new_m, new_v = codec.adamw_update_shard(
+                g_shard, p_shard, opt_state["m"], opt_state["v"],
+                clip=clip, lr=lr, bc1=bc1, bc2=bc2, b1=opt.b1, b2=opt.b2,
+                eps=opt.eps, weight_decay=opt.weight_decay, wire=zero_wire)
 
-        if zero_wire == "int8":
-            if overlap:
-                full = ov.scan_bucket_reduce((p_wire, p_scales), zero_ag_q)
+        with telemetry.scope("exchange"):
+            if zero_wire == "int8":
+                if overlap:
+                    full = ov.scan_bucket_reduce((p_wire, p_scales), zero_ag_q)
+                else:
+                    full = jnp.stack([zero_ag_q((p_wire[k], p_scales[k]))
+                                      for k in range(nb)])
+            elif overlap:
+                full = ov.scan_bucket_reduce(p_wire, zero_ag)
             else:
-                full = jnp.stack([zero_ag_q((p_wire[k], p_scales[k]))
-                                  for k in range(nb)])
-        elif overlap:
-            full = ov.scan_bucket_reduce(p_wire, zero_ag)
-        else:
-            full = jnp.stack([zero_ag(p_wire[k]) for k in range(nb)])
-        new_flat = codec.unpack(table, full[:, :cap], flat_p)
+                full = jnp.stack([zero_ag(p_wire[k]) for k in range(nb)])
+            new_flat = codec.unpack(table, full[:, :cap], flat_p)
         new_params = tdef.unflatten(
             [r.astype(p.dtype) for r, p in zip(new_flat, flat_p)])
         metrics = {"grad_norm": gnorm, "lr": lr, "loss": loss}
@@ -431,6 +445,7 @@ def build_explicit_dp_step(model: Model, opt: adamw.OptConfig, mesh, axis: str =
         return ov.quantized_all_reduce(q_row, s_row, axis, dcn_axis=dcn_axis,
                                        n_chunks=n_chunks)
 
+    @telemetry.scope("exchange")
     def reduce_bucketed(flat_g, err):
         """Pack the flat gradient stream into bucket_bytes rows (tensors split
         at bucket boundaries, forward order) and reduce each eagerly — exactly
@@ -490,19 +505,20 @@ def build_explicit_dp_step(model: Model, opt: adamw.OptConfig, mesh, axis: str =
             table = codec.make_table([g.size for g in flat_g], bucket_elems)
             if table.n_buckets == 0:  # every gradient leaf is zero-size
                 return loss, [g.astype(jnp.float32) for g in flat_g], tdef, err
-            if compress_bits == 8:
-                q, s, new_err = codec.pack(table, flat_g, scale=inv,
-                                           wire="int8", err=err)
-                # scan-carried issue schedule over the quantized carrier: one
-                # int8 bucket (+ scale) in flight at a time
-                reduced = ov.scan_bucket_reduce(
-                    (q, s), partial(reduce_q, n_chunks=chunks))
-                return loss, codec.unpack(table, reduced, flat_g), tdef, new_err
-            carrier, _, _ = codec.pack(table, flat_g, scale=inv)
-            # scan-carried issue schedule: one bucket in flight at a time, in
-            # the order backward materializes them
-            reduced = ov.scan_bucket_reduce(carrier, reduce_bucket)
-            return loss, codec.unpack(table, reduced, flat_g), tdef, err
+            with telemetry.scope("exchange"):
+                if compress_bits == 8:
+                    q, s, new_err = codec.pack(table, flat_g, scale=inv,
+                                               wire="int8", err=err)
+                    # scan-carried issue schedule over the quantized carrier: one
+                    # int8 bucket (+ scale) in flight at a time
+                    reduced = ov.scan_bucket_reduce(
+                        (q, s), partial(reduce_q, n_chunks=chunks))
+                    return loss, codec.unpack(table, reduced, flat_g), tdef, new_err
+                carrier, _, _ = codec.pack(table, flat_g, scale=inv)
+                # scan-carried issue schedule: one bucket in flight at a time, in
+                # the order backward materializes them
+                reduced = ov.scan_bucket_reduce(carrier, reduce_bucket)
+                return loss, codec.unpack(table, reduced, flat_g), tdef, err
 
         mb = _microbatch(batch, microbatches)
         mb0 = jax.tree.map(lambda a: a[0], mb)
@@ -515,64 +531,84 @@ def build_explicit_dp_step(model: Model, opt: adamw.OptConfig, mesh, axis: str =
                              "elements to reduce (all leaves zero-size)")
 
         if compress_bits == 8:
-            q0, s0, err1 = codec.pack(table, flat0, scale=inv, wire="int8",
-                                      err=err)
+            with telemetry.scope("exchange"):
+                q0, s0, err1 = codec.pack(table, flat0, scale=inv, wire="int8",
+                                          err=err)
 
             def body_q(carry, b):
                 acc, q_p, s_p, err_c, lsum = carry
                 # issue the previous microbatch's quantized bucket reductions
                 # FIRST: no data dependency on this microbatch's backward
-                reduced = jnp.stack([reduce_q((q_p[k], s_p[k]),
-                                              n_chunks=chunks)
-                                     for k in range(nb)])
+                with telemetry.scope("exchange"):
+                    reduced = jnp.stack([reduce_q((q_p[k], s_p[k]),
+                                                  n_chunks=chunks)
+                                         for k in range(nb)])
                 loss, flat, _ = grads_of(b)
-                # per-bucket error feedback carried through the scan
-                q_n, s_n, err_c = codec.pack(table, flat, scale=inv,
-                                             wire="int8", err=err_c)
-                return (acc + reduced, q_n, s_n, err_c, lsum + loss), None
+                with telemetry.scope("exchange"):
+                    # per-bucket error feedback carried through the scan
+                    q_n, s_n, err_c = codec.pack(table, flat, scale=inv,
+                                                 wire="int8", err=err_c)
+                with telemetry.scope("grad_accum"):
+                    acc, lsum = acc + reduced, lsum + loss
+                return (acc, q_n, s_n, err_c, lsum), None
 
             init = (jnp.zeros((nb, table.bucket_elems), jnp.float32),
                     q0, s0, err1, loss0)
             (acc, q_p, s_p, err_c, lsum), _ = jax.lax.scan(body_q, init, rest)
-            final = jnp.stack([reduce_q((q_p[k], s_p[k]), n_chunks=chunks)
-                               for k in range(nb)])
-            loss = lsum / microbatches
-            return (loss, codec.unpack(table, acc + final, flat0), tdef, err_c)
+            with telemetry.scope("exchange"):
+                final = jnp.stack([reduce_q((q_p[k], s_p[k]), n_chunks=chunks)
+                                   for k in range(nb)])
+            with telemetry.scope("grad_accum"):
+                reduced = acc + final
+                loss = lsum / microbatches
+            with telemetry.scope("exchange"):
+                return (loss, codec.unpack(table, reduced, flat0), tdef, err_c)
 
-        pending0, _, _ = codec.pack(table, flat0, scale=inv)
+        with telemetry.scope("exchange"):
+            pending0, _, _ = codec.pack(table, flat0, scale=inv)
 
         def body(carry, b):
             acc, pending, lsum = carry
             # issue the previous microbatch's bucket reductions FIRST: they
             # have no data dependency on this microbatch's backward, so the
             # scheduler overlaps the reduction stream with the backward compute
-            reduced = jnp.stack([reduce_bucket(pending[k])
-                                 for k in range(nb)])
+            with telemetry.scope("exchange"):
+                reduced = jnp.stack([reduce_bucket(pending[k])
+                                     for k in range(nb)])
             loss, flat, _ = grads_of(b)
-            nxt, _, _ = codec.pack(table, flat, scale=inv)
-            return (acc + reduced, nxt, lsum + loss), None
+            with telemetry.scope("exchange"):
+                nxt, _, _ = codec.pack(table, flat, scale=inv)
+            with telemetry.scope("grad_accum"):
+                acc, lsum = acc + reduced, lsum + loss
+            return (acc, nxt, lsum), None
 
         init = (jnp.zeros_like(pending0), pending0, loss0)
         (acc, pending, lsum), _ = jax.lax.scan(body, init, rest)
         # flush: the last microbatch's buckets have no backward left to hide
         # behind — this is the exposed tail the predictor charges for
-        final = jnp.stack([reduce_bucket(pending[k]) for k in range(nb)])
-        reduced = acc + final
-        loss = lsum / microbatches
-        return loss, codec.unpack(table, reduced, flat0), tdef, err
+        with telemetry.scope("exchange"):
+            final = jnp.stack([reduce_bucket(pending[k]) for k in range(nb)])
+        with telemetry.scope("grad_accum"):
+            reduced = acc + final
+            loss = lsum / microbatches
+        with telemetry.scope("exchange"):
+            return loss, codec.unpack(table, reduced, flat0), tdef, err
 
     def local_step(params, opt_state, batch, err):
         if overlap:
             loss, red_flat, tdef, new_err = overlap_grads(params, batch, err)
-            loss = jax.lax.pmean(loss, loss_axes)
+            with telemetry.scope("exchange"):
+                loss = jax.lax.pmean(loss, loss_axes)
             grads = tdef.unflatten(red_flat)
             params, opt_state, metrics = adamw.apply_updates(params, grads,
                                                              opt_state, opt)
             metrics["loss"] = loss
             return params, opt_state, metrics, new_err
         loss, grads = jax.value_and_grad(model.loss)(params, batch)
-        loss = jax.lax.pmean(loss, loss_axes)
+        with telemetry.scope("exchange"):
+            loss = jax.lax.pmean(loss, loss_axes)
 
+        @telemetry.scope("exchange")
         def reduce_one(g, e):
             g32 = g.astype(jnp.float32) / n_total
             if compress_bits == 8:

@@ -45,6 +45,7 @@ from ..data.pipeline import SyntheticLM, DataConfig
 from ..models.model import build_model
 from ..models.sharding import tree_shardings_shaped
 from ..optim import adamw
+from .. import telemetry
 from . import steps as rsteps
 from .guard import DriftGuard, GuardConfig
 
@@ -154,10 +155,15 @@ class Trainer:
             self.guard = DriftGuard(gcfg, replanner=self._replan)
         self.skipped_steps = 0
         self.retry_log: list = []
+        # programs built (compiled or loaded) before the next metrics row
+        self._compiles_seen = telemetry.COMPILES.total
         self._build(self.mesh)
 
     # ----------------------------------------------------------------- build
     def _build(self, mesh):
+        # steps dispatched since this build: the second one is noted
+        # (`_note_step`), with the inputs every later step sees
+        self._steps_built = 0
         if self.cfg.explicit_dp:
             if mesh is None:
                 raise ValueError("explicit_dp requires a multi-device mesh; "
@@ -172,11 +178,14 @@ class Trainer:
         self.bundle = rsteps.train_step_bundle(self.model, self.shape, self.opt,
                                                microbatches=self.cfg.microbatches)
         if mesh is not None:
-            self.step_fn = jax.jit(self.bundle.fn, in_shardings=self.bundle.in_shardings,
-                                   out_shardings=self.bundle.out_shardings,
-                                   donate_argnums=self.bundle.donate_argnums)
+            self._step_jit = jax.jit(self.bundle.fn,
+                                     in_shardings=self.bundle.in_shardings,
+                                     out_shardings=self.bundle.out_shardings,
+                                     donate_argnums=self.bundle.donate_argnums)
         else:
-            self.step_fn = jax.jit(self.bundle.fn, donate_argnums=self.bundle.donate_argnums)
+            self._step_jit = jax.jit(self.bundle.fn,
+                                     donate_argnums=self.bundle.donate_argnums)
+        self.step_fn = self._step_jit
 
     def _build_explicit_dp(self, mesh):
         """Explicit-DP: replicated params (model built without mesh constraints),
@@ -258,91 +267,71 @@ class Trainer:
         retries = 0
         skip = self.cfg.straggler_action == "skip"
         while step < self.cfg.steps:
-            batch = {k: jax.numpy.asarray(v) for k, v in self.data.batch_at(step).items()}
-            # 'skip' reverts to the pre-step state after the fact, so it needs
-            # copies taken before the step (the step may donate its inputs)
-            prev = None
-            if skip:
-                prev = (jax.tree.map(jax.numpy.copy, params),
-                        jax.tree.map(jax.numpy.copy, opt_state))
-            t0 = time.perf_counter()
-            try:
-                if pending_inject and step == pending_inject[0]:
-                    pending_inject.pop(0)
-                    raise RuntimeError("injected device failure (test)")
-                if self.injector is not None:
-                    self.injector.before_step(step)
-                params, opt_state, metrics = self.step_fn(params, opt_state, batch)
-                jax.block_until_ready(metrics["loss"])
-            except NodeLossFault as e:
-                # elastic re-mesh: rebuild on the surviving device set, then
-                # restore the last checkpoint onto the shrunk mesh
-                self.ckpt.wait()
-                if self.ckpt.latest_step() is None:
-                    raise
-                self.mesh = self._surviving_mesh(e.lost)
-                self._build(self.mesh)
-                params, opt_state, step = self.restore()
-                self.straggler.reset_baseline()
-                retries = 0
-                continue
-            except (RuntimeError, jax.errors.JaxRuntimeError) as e:
-                if not _is_transient(e):
-                    raise  # a genuine bug, not a fabric fault: propagate
-                self.ckpt.wait()
-                restored = self.ckpt.latest_step()
-                if restored is None:
-                    raise  # nothing to restore into: surface the fault
-                retries += 1
-                self.retry_log.append({"step": step, "attempt": retries,
-                                       "error": str(e)[:200]})
-                if retries > self.cfg.max_retries:
-                    raise RuntimeError(
-                        f"persistent failure: {retries - 1} consecutive "
-                        f"restore-and-replay attempts failed at step {step} "
-                        f"(last error: {e})") from e
-                time.sleep(self.cfg.retry_backoff_s * 2 ** (retries - 1))
-                self._build(self.mesh)
-                params, opt_state, step = self.restore()
-                continue
-            retries = 0
-            dt = time.perf_counter() - t0
-            if self.injector is not None:
-                # the simulated messy fabric: degradation windows perturb the
-                # measured step time (deterministically, per the FaultPlan)
-                dt = self.injector.perturb(step, dt)
-            ev = self.straggler.observe(step, dt)
-            if ev is not None:
-                if self.cfg.straggler_action == "sync":
-                    jax.block_until_ready(params)
-                elif skip:
-                    # drop the straggler step's update entirely (and its
-                    # error-feedback contribution): the replicated state
-                    # reverts to the pre-step snapshot
-                    params, opt_state = prev
-                    self._dp_err = None
-                    self.skipped_steps += 1
-            if self.guard is not None:
-                gev = self.guard.observe(step, dt)
-                if gev is not None and gev.kind == "replan":
-                    # the swap changed the step-time population on both
-                    # trackers; the injector models the re-ranked plan's
-                    # partial recovery on simulated fabrics
-                    self.straggler.reset_baseline()
+            with telemetry.step_span(telemetry.TRAIN_STEP, step):
+                with telemetry.span("train.batch"):
+                    batch = {k: jax.numpy.asarray(v)
+                             for k, v in self.data.batch_at(step).items()}
+                # 'skip' reverts to the pre-step state after the fact, so it
+                # needs copies taken before the step (the step may donate its
+                # inputs)
+                prev = None
+                if skip:
+                    prev = (jax.tree.map(jax.numpy.copy, params),
+                            jax.tree.map(jax.numpy.copy, opt_state))
+                t0 = time.perf_counter()
+                try:
+                    if pending_inject and step == pending_inject[0]:
+                        pending_inject.pop(0)
+                        raise RuntimeError("injected device failure (test)")
                     if self.injector is not None:
-                        self.injector.on_replan(
-                            self.guard.cfg.recovered_fraction)
-            row = {"step": step, "loss": float(metrics["loss"]),
-                   "grad_norm": float(metrics["grad_norm"]),
-                   "lr": float(metrics["lr"]), "time_s": dt,
-                   "straggler": ev is not None}
-            self.metrics_log.append(row)
-            if step % self.cfg.log_every == 0:
-                print(f"step {step:5d} loss {row['loss']:.4f} "
-                      f"gnorm {row['grad_norm']:.3f} {dt*1e3:.0f}ms", flush=True)
-            step += 1
-            if self.cfg.ckpt_every and step % self.cfg.ckpt_every == 0:
-                self.save(step, params, opt_state)
+                        self.injector.before_step(step)
+                    if self._steps_built == 1:
+                        self._note_step(params, opt_state, batch)
+                    self._steps_built += 1
+                    with telemetry.span("train.step"):
+                        params, opt_state, metrics = self.step_fn(params, opt_state,
+                                                                  batch)
+                    with telemetry.span("train.sync"):
+                        jax.block_until_ready(metrics["loss"])
+                except NodeLossFault as e:
+                    # elastic re-mesh: rebuild on the surviving device set,
+                    # then restore the last checkpoint onto the shrunk mesh
+                    self.ckpt.wait()
+                    if self.ckpt.latest_step() is None:
+                        raise
+                    self.mesh = self._surviving_mesh(e.lost)
+                    self._build(self.mesh)
+                    params, opt_state, step = self.restore()
+                    self.straggler.reset_baseline()
+                    retries = 0
+                    continue
+                except (RuntimeError, jax.errors.JaxRuntimeError) as e:
+                    if not _is_transient(e):
+                        raise  # a genuine bug, not a fabric fault: propagate
+                    self.ckpt.wait()
+                    restored = self.ckpt.latest_step()
+                    if restored is None:
+                        raise  # nothing to restore into: surface the fault
+                    retries += 1
+                    self.retry_log.append({"step": step, "attempt": retries,
+                                           "error": str(e)[:200]})
+                    if retries > self.cfg.max_retries:
+                        raise RuntimeError(
+                            f"persistent failure: {retries - 1} consecutive "
+                            f"restore-and-replay attempts failed at step {step} "
+                            f"(last error: {e})") from e
+                    time.sleep(self.cfg.retry_backoff_s * 2 ** (retries - 1))
+                    self._build(self.mesh)
+                    params, opt_state, step = self.restore()
+                    continue
+                retries = 0
+                dt = time.perf_counter() - t0
+                with telemetry.span("train.host"):
+                    params, opt_state = self._after_step(step, dt, params,
+                                                         opt_state, metrics, prev)
+                    step += 1
+                    if self.cfg.ckpt_every and step % self.cfg.ckpt_every == 0:
+                        self.save(step, params, opt_state)
         self.save(step, params, opt_state)
         self.ckpt.wait()
         out = {"final_step": step, "metrics": self.metrics_log,
@@ -356,6 +345,56 @@ class Trainer:
         if self.injector is not None:
             out["fault_log"] = list(self.injector.log)
         return out
+
+    def _note_step(self, params, opt_state, batch) -> None:
+        """Note the step program for reading its compiled text back."""
+        if self._dp_step is None:
+            telemetry.note_program("train.step", self._step_jit.lower,
+                                   params, opt_state, batch)
+        else:
+            telemetry.note_program("train.step", self._dp_step.lower,
+                                   params, opt_state, batch, self._dp_err)
+
+    def _after_step(self, step: int, dt: float, params, opt_state, metrics, prev):
+        """Straggler and guard bookkeeping, the metrics row and its log line;
+        returns the state to carry on from (the pre-step copy under 'skip')."""
+        if self.injector is not None:
+            # the simulated messy fabric: degradation windows perturb the
+            # measured step time (deterministically, per the FaultPlan)
+            dt = self.injector.perturb(step, dt)
+        ev = self.straggler.observe(step, dt)
+        if ev is not None:
+            if self.cfg.straggler_action == "sync":
+                jax.block_until_ready(params)
+            elif self.cfg.straggler_action == "skip":
+                # drop the straggler step's update entirely (and its
+                # error-feedback contribution): the replicated state
+                # reverts to the pre-step snapshot
+                params, opt_state = prev
+                self._dp_err = None
+                self.skipped_steps += 1
+        if self.guard is not None:
+            gev = self.guard.observe(step, dt)
+            if gev is not None and gev.kind == "replan":
+                # the swap changed the step-time population on both
+                # trackers; the injector models the re-ranked plan's
+                # partial recovery on simulated fabrics
+                self.straggler.reset_baseline()
+                if self.injector is not None:
+                    self.injector.on_replan(
+                        self.guard.cfg.recovered_fraction)
+        compiles = telemetry.COMPILES.total
+        row = {"step": step, "loss": float(metrics["loss"]),
+               "grad_norm": float(metrics["grad_norm"]),
+               "lr": float(metrics["lr"]), "time_s": dt,
+               "straggler": ev is not None,
+               "compiles": compiles - self._compiles_seen}
+        self._compiles_seen = compiles
+        self.metrics_log.append(row)
+        if step % self.cfg.log_every == 0:
+            print(f"step {step:5d} loss {row['loss']:.4f} "
+                  f"gnorm {row['grad_norm']:.3f} {dt*1e3:.0f}ms", flush=True)
+        return params, opt_state
 
     # ----------------------------------------------------------- re-planning
     def _replan(self, step: int):
