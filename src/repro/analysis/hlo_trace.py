@@ -106,6 +106,10 @@ MODULE_RE = re.compile(r"^HloModule\s+([\w.\-]+)")
 OP_NAME_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=(?:.*?\bmetadata=\{[^\n]*?"
     r"\bop_name=\"((?:[^\"\\]|\\.)*)\")?", re.M)
+# a line break that starts no instruction: inside one whose attributes span
+# lines (a Pallas kernel's custom call prints its kernel metadata on lines
+# of their own, before its op_name)
+CONTINUATION_RE = re.compile(r"\n(?!\s*(?:ROOT\s+)?%?[\w.\-]+\s*=)")
 
 #: payloads below this are sideband/control traffic (mirrors
 #: ``analysis.expect.WIDE_BYTES``; duplicated literal avoided via import there)
@@ -252,7 +256,8 @@ def op_names(hlo_text: str) -> Dict[str, str]:
     """Instruction name -> the ``op_name`` of its metadata (the jaxpr path,
     named scopes included), ``""`` for an instruction without one (a copy or
     layout change the compiler added)."""
-    return {m.group(1): m.group(2) or "" for m in OP_NAME_RE.finditer(hlo_text)}
+    one_per_line = CONTINUATION_RE.sub(" ", hlo_text)
+    return {m.group(1): m.group(2) or "" for m in OP_NAME_RE.finditer(one_per_line)}
 
 
 def trip_count(cond_lines: List[str]) -> int:

@@ -37,7 +37,7 @@ class ModelConfig:
     n_codebooks: int = 0            # audio: EnCodec codebooks
     n_img_tokens: int = 0           # vlm: precomputed patch embeddings per sample
     # --- implementation knobs (the tuning surface; paper Obs. 1) ---
-    attn_impl: str = "blockwise"    # blockwise | naive | pallas
+    attn_impl: str = "flash"        # flash | pallas | blockwise | naive
     q_block: int = 256
     use_scan: bool = True           # scan over layers (compile-time/HLO size)
     remat: str = "block"            # none | block  (activation checkpointing)

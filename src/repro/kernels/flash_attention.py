@@ -1,100 +1,89 @@
-"""Pallas TPU flash attention (forward): blocked causal attention, online softmax.
+"""Causal flash attention with a backward pass: the TPU splash-attention
+kernels of the installed JAX (``jax.experimental.pallas.ops.tpu``), in their
+MQA form.
 
-TPU mapping (DESIGN.md Sec. 6): grid = (batch*heads, q_blocks, kv_blocks) with
-the kv dimension sequential ("arbitrary" semantics); per-(bh, qb) running max /
-normalizer / accumulator live in VMEM scratch across kv iterations.  Block shapes
-are (q_block, head_dim) / (kv_block, head_dim) — multiples of the (8, 128) TPU
-tile; head_dim 64/128 aligns the MXU contraction.
+One kernel call covers one KV head: its G = H / K query heads are the
+kernel's head axis, and the call is ``vmap``ped over batch and KV heads, so
+keys and values enter as (B, K, S, hd) and are never repeated to H heads.
+The forward keeps float32 running max and normaliser in VMEM and skips every
+key block above the diagonal (the causal mask's block info); the backward
+recomputes the probabilities from the saved log-sum-exp, block by block.
+Matmul operands stay in the input dtype with float32 accumulation, except
+that the forward's P·V takes P and V in float32.
 
-Validated against ref.py in interpret mode on the CPU; compiled by Mosaic on
-TPU (see tests/test_tpu_compile.py).
+The kernel applies no scale: 1/sqrt(hd) is folded into q, exactly for a
+power-of-two scale (hd 64, 256), else in float32 and rounded once to q's
+dtype.  Validated against ``ref.attention_ref`` in interpret mode on the
+CPU; compiled by Mosaic for a described v5e in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from .interpret import interpret_mode
 
-NEG_INF = -1e30
+#: the kernel's tile widths, largest first: a block is a multiple of 128
+#: (the TPU lane width) that divides the sequence.  The largest that divides
+#: it serves forward and backward alike: at S=4096, hd 64 on a v5e, 1024 beat
+#: 512 in both (PERF.md, section 5)
+BLOCKS = (1024, 512, 256, 128)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-                  *, causal: bool, q_block: int, kv_block: int, scale: float):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    run = True
-    if causal:
-        # whole kv block strictly above the diagonal? skip.
-        run = (ki * kv_block) <= (qi * q_block + q_block - 1)
-
-    @pl.when(run if causal else True)
-    def _body():
-        q = q_ref[0, :, :].astype(jnp.float32)            # (qb, hd)
-        k = k_ref[0, :, :].astype(jnp.float32)            # (kb, hd)
-        v = v_ref[0, :, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = qi * q_block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            kpos = ki * kv_block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        m_prev = m_scr[...]                                # (qb, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
-    @pl.when(ki == nk - 1)
-    def _final():
-        o_ref[0, :, :] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+def block_sizes(seq: int, head_dim: int) -> Optional[splash.BlockSizes]:
+    """The forward and backward tiles for a (seq, head_dim) problem, or None
+    where the kernel cannot take it (seq not a multiple of 128, head_dim
+    not a multiple of 64)."""
+    if head_dim % 64:
+        return None
+    blk = next((b for b in BLOCKS if seq % b == 0), None)
+    if blk is None:
+        return None
+    return splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk,
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+        use_fused_bwd_kernel=True)
 
 
-def flash_attention_fwd(q, k, v, *, causal: bool = True, q_block: int = 128,
-                        kv_block: int = 128, interpret=None) -> jnp.ndarray:
-    """q, k, v: (BH, S, hd) — batch and heads pre-merged, kv pre-repeated to H.
-    Returns (BH, S, hd)."""
-    bh, s, hd = q.shape
-    q_block = min(q_block, s)
-    kv_block = min(kv_block, s)
-    assert s % q_block == 0 and s % kv_block == 0
-    nq, nk = s // q_block, s // kv_block
+@functools.lru_cache(maxsize=None)
+def _kernel(seq: int, groups: int, blocks: splash.BlockSizes, causal: bool,
+            interpret: bool):
+    """The MQA kernel for ``groups`` query heads over one (seq, hd) key and
+    value; the mask's block info is numpy work, done once per shape, and
+    held as concrete arrays whatever trace first asks for it."""
+    one = splash.CausalMask((seq, seq)) if causal else splash.FullMask((seq, seq))
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mqa_single_device(
+            splash.MultiHeadMask([one] * groups), block_sizes=blocks,
+            interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "blocks", "interpret"))
+def flash_attention(q, k, v, *, causal: bool = True,
+                    blocks: Optional[splash.BlockSizes] = None,
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
+    """q: (B, S, H, hd); k, v: (B, S, K, hd) with K | H.  Returns (B, S, H, hd).
+
+    ``blocks`` defaults to ``block_sizes(S, hd)``; ``interpret`` resolves
+    through ``interpret_mode`` (compiled on a TPU, interpreted elsewhere)."""
+    b, s, h, hd = q.shape
+    kh = k.shape[2]
+    g = h // kh
+    blocks = blocks or block_sizes(s, hd)
+    if blocks is None or h % kh or k.shape[1] != s:
+        raise ValueError(f"flash_attention cannot take q {q.shape}, k {k.shape}")
     scale = 1.0 / math.sqrt(hd)
-    kernel = functools.partial(_flash_kernel, causal=causal, q_block=q_block,
-                               kv_block=kv_block, scale=scale)
-    return pl.pallas_call(
-        kernel,
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, q_block, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, kv_block, hd), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, kv_block, hd), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, q_block, hd), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, s, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((q_block, 1), jnp.float32),    # running max
-            pltpu.VMEM((q_block, 1), jnp.float32),    # normalizer
-            pltpu.VMEM((q_block, hd), jnp.float32),   # output accumulator
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret_mode(interpret),
-    )(q, k, v)
+    if scale == 2.0 ** round(math.log2(scale)):     # exact in any float dtype
+        q = q * jnp.asarray(scale, q.dtype)
+    else:
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    kernel = _kernel(s, g, blocks, causal, interpret_mode(interpret))
+    qg = q.reshape(b, s, kh, g, hd).transpose(0, 2, 3, 1, 4)   # (B, K, G, S, hd)
+    kt, vt = (t.transpose(0, 2, 1, 3) for t in (k, v))         # (B, K, S, hd)
+    out = jax.vmap(jax.vmap(kernel))(qg, kt, vt)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, hd)

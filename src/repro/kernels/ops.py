@@ -1,32 +1,18 @@
 """Jit'd public wrappers around the Pallas kernels (shape adaptation + dispatch).
 
 `interpret=None` lets `kernels.interpret.interpret_mode` decide: compiled on a
-TPU, interpreted elsewhere.  The model reaches these via cfg.attn_impl ==
-"pallas".
+TPU, interpreted elsewhere.  Attention's kernel, with its backward, is
+`kernels.flash_attention.flash_attention`, which `models.layers.attention`
+calls.
 """
 from __future__ import annotations
 
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
-from . import flash_attention as _fa
 from . import rmsnorm as _rn
 from . import ssd_scan as _ssd
-
-
-@partial(jax.jit, static_argnames=("causal", "q_block", "kv_block", "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, q_block: int = 128,
-                    kv_block: int = 128, interpret=None):
-    """q: (B, S, H, hd); k, v: (B, S, H, hd) (kv already repeated to H heads).
-    Returns (B, S, H, hd)."""
-    b, s, h, hd = q.shape
-    fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
-    out = _fa.flash_attention_fwd(fold(q), fold(k), fold(v), causal=causal,
-                                  q_block=q_block, kv_block=kv_block,
-                                  interpret=interpret)
-    return out.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
 
 
 @partial(jax.jit, static_argnames=("eps", "interpret"))

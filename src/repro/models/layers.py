@@ -1,11 +1,16 @@
-"""Shared neural building blocks: RMSNorm, RoPE, GQA attention (blockwise /
-naive / sequence-sharded decode), MLPs.
+"""Shared neural building blocks: RMSNorm, RoPE, GQA attention (fused /
+blockwise / naive / sequence-sharded decode), MLPs.
 
-Attention implementations:
-  * naive      — full (S x S) scores; reference/oracle only.
+Attention implementations (``attention(impl=...)``, ``ModelConfig.attn_impl``):
+  * flash      — the default: the fused causal flash kernel with its backward
+                 (kernels/flash_attention, grouped kv, masked blocks skipped)
+                 where it compiles — a TPU backend, causal with no query
+                 offset, S a multiple of the kernel's block, hd a multiple of
+                 64, no context parallelism — and the blockwise scan elsewhere.
+  * pallas     — the fused kernel, forced (interpreted off a TPU).
   * blockwise  — lax.scan over query blocks with a bounded score tile; identical
-                 math, memory O(q_block * S) instead of O(S^2).  This is also the
-                 jnp twin of kernels/flash_attention (the Pallas TPU kernel).
+                 math, memory O(q_block * S) instead of O(S^2).
+  * naive      — full (S x S) scores; reference/oracle only.
   * decode     — one query position against a KV cache whose *sequence* dimension
                  is sharded over the `model` mesh axis ("seq" logical axis): XLA
                  partitions the contraction and inserts the psum — the TPU-native
@@ -20,7 +25,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from .. import telemetry
+from ..kernels import flash_attention as fa
 from .sharding import Sharder
 
 
@@ -45,13 +53,23 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., S, H, hd); positions: (..., S)."""
+    """x: (..., S, H, hd); positions: (..., S).
+
+    (x1, x2) -> (x1 cos - x2 sin, x2 cos + x1 sin) in float32, written as
+    x cos + rot(x) sin over the whole head, with rot(x) = (-x2, x1) a matmul
+    by an exact signed permutation.  The result is the same; on a TPU the
+    half-width split and concatenate, laid out for the fused attention
+    kernel, cost 30 ms of a 0.70 s smollm-135m training step on a TPU v5e
+    (PERF.md)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
-    ang = positions[..., :, None, None].astype(jnp.float32) * freqs  # (..., S, 1, hd/2)
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    freqs = jnp.tile(rope_freqs(hd, theta), 2)          # (hd,): each half's
+    ang = positions[..., :, None, None].astype(jnp.float32) * freqs  # (..., S, 1, hd)
+    half = np.eye(hd // 2, dtype=np.float32)
+    rot = np.block([[0 * half, half], [-half, 0 * half]])   # x @ rot = (-x2, x1)
+    xr = jnp.einsum("...d,de->...e", x, jnp.asarray(rot, x.dtype),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+    out = x.astype(jnp.float32) * jnp.cos(ang) + xr * jnp.sin(ang)
     return out.astype(x.dtype)
 
 
@@ -150,14 +168,67 @@ def decode_attention(q, k_cache, v_cache, pos, *, shd: Optional[Sharder] = None)
     return out.reshape(b, 1, h, hd).astype(q.dtype)
 
 
-def attention(q, k, v, *, impl: str = "blockwise", causal: bool = True,
+def _fused_specs(q, k, shd: Optional[Sharder]):
+    """None where the fused kernel runs on the arrays as they are (no mesh,
+    or one device); else the (q, kv) PartitionSpecs of its per-shard call:
+    batch over the data axes, heads over `tp` where both H and K divide it.
+    Raises ValueError where the mesh cannot be split that way."""
+    if shd is None or shd.mesh is None or shd.mesh.size == 1:
+        return None
+    tp = shd.axis_size("tp")
+    if tp > 1 and (q.shape[2] % tp or k.shape[2] % tp):
+        raise ValueError(f"{q.shape[2]} / {k.shape[2]} heads do not divide tp={tp}")
+    if q.shape[0] % shd.axis_size("batch"):
+        raise ValueError(f"batch {q.shape[0]} does not divide the data axes")
+    heads = "tp" if tp > 1 else None
+    return (shd.spec(("batch", None, heads, None), q.shape),
+            shd.spec(("batch", None, heads, None), k.shape))
+
+
+def _fused(q, k, v, *, causal: bool, shd: Optional[Sharder]):
+    specs = _fused_specs(q, k, shd)
+    if specs is None:
+        return fa.flash_attention(q, k, v, causal=causal)
+    from jax import shard_map
+    q_spec, kv_spec = specs
+    return shard_map(partial(fa.flash_attention, causal=causal), mesh=shd.mesh,
+                     in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec,
+                     check_vma=False)(q, k, v)
+
+
+def _fused_fits(q, k, *, causal: bool, q_offset: int,
+                shd: Optional[Sharder]) -> bool:
+    """Whether the default impl takes the fused kernel: TPU backend, causal
+    with no offset, a shape the kernel tiles, a mesh it can be split over."""
+    if jax.default_backend() != "tpu" or not causal or q_offset:
+        return False
+    if q.shape[1] != k.shape[1] or fa.block_sizes(q.shape[1], q.shape[3]) is None:
+        return False
+    try:
+        _fused_specs(q, k, shd)
+    except ValueError:
+        return False
+    return True
+
+
+def attention(q, k, v, *, impl: str = "flash", causal: bool = True,
               q_block: int = 256, q_offset: int = 0,
               shd: Optional[Sharder] = None) -> jnp.ndarray:
     """Dispatch over implementations; kv is (B, S, K, hd) with K | H.
 
+    The fused kernel takes the K kv heads as they are; the scan and naive
+    paths repeat them to H.  Each call counts its path in
+    ``telemetry.ATTENTION_PATHS``.
+
     Sharding: heads over `model` when the head count divides the TP axis
     (Megatron-style); otherwise context-parallel query sharding inside the
     blockwise scan (see blockwise_attention)."""
+    if impl == "pallas" or (impl == "flash" and _fused_fits(
+            q, k, causal=causal, q_offset=q_offset, shd=shd)):
+        if q_offset:
+            raise ValueError("the fused kernel takes no query offset")
+        telemetry.ATTENTION_PATHS["fused"] += 1
+        return _fused(q, k, v, causal=causal, shd=shd)
     h = q.shape[2]
     kh = k.shape[2]
     k = _repeat_kv(k, h // kh)
@@ -175,11 +246,10 @@ def attention(q, k, v, *, impl: str = "blockwise", causal: bool = True,
         # unlike query sharding, whose backward all-reduces dk/dv per block.
         k = shd.constrain(k, "batch", "seq", None, None)
         v = shd.constrain(v, "batch", "seq", None, None)
-    if impl == "pallas":
-        from ..kernels import ops as kops
-        return kops.flash_attention(q, k, v, causal=causal)
     if impl == "naive":
+        telemetry.ATTENTION_PATHS["naive"] += 1
         return naive_attention(q, k, v, causal=causal, q_offset=q_offset, shd=shd)
+    telemetry.ATTENTION_PATHS["scan"] += 1
     return blockwise_attention(q, k, v, causal=causal, q_block=q_block,
                                q_offset=q_offset, shd=shd,
                                context_parallel=False)

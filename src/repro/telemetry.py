@@ -10,6 +10,8 @@
   forward and the backward pass.  An op belongs to its innermost scope.
 * ``COMPILES`` counts the process's backend compiles and executables loaded
   from the persistent cache, with their seconds.
+* ``ATTENTION_PATHS`` counts, by path, the calls of
+  ``models.layers.attention``: one per trace of a program that attends.
 * ``note_program(name, lower, *args)``, called once per program built,
   keeps how to fetch the compiled text of the program a span dispatches (the
   arguments' shapes and shardings, never the arrays), so the profile's op
@@ -45,6 +47,9 @@ SCOPES = ("attention", "mlp", "head", "mixer", "grad_accum", "optimizer",
           "exchange")
 #: programs noted by ``note_program``
 PROGRAMS = ("train.step", "serve.prefill", "serve.decode")
+#: the paths ``models.layers.attention`` counts in ``ATTENTION_PATHS``: the
+#: fused flash kernel, the blockwise scan, the naive oracle
+ATTENTION_PATH_NAMES = ("fused", "scan", "naive")
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
@@ -55,6 +60,8 @@ recording = jax.profiler.TraceAnnotation.is_enabled
 SPANS: collections.deque = collections.deque(maxlen=1 << 20)
 #: program name -> () -> compiled HLO text (``note_program``)
 NOTED: Dict[str, Callable[[], str]] = {}
+#: attention path (``ATTENTION_PATH_NAMES``) -> times it was traced
+ATTENTION_PATHS: collections.Counter = collections.Counter()
 
 
 @contextlib.contextmanager

@@ -1,80 +1,150 @@
 """Per-kernel allclose vs ref.py oracles: shape/dtype sweeps + hypothesis."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
+from repro.models.layers import blockwise_attention
 
 RNG = np.random.RandomState(0)
 
 
 def _attn_ref_4d(q, k, v, causal=True):
+    """ref.attention_ref over (B, S, H, hd), k and v repeated to H heads."""
     b, s, h, hd = q.shape
+    g = h // k.shape[2]
+    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
     out = ref.attention_ref(fold(q), fold(k), fold(v), causal=causal)
     return out.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
 
 
-@pytest.mark.parametrize("b,s,h,hd", [(2, 256, 4, 64), (1, 128, 2, 128),
-                                      (2, 512, 3, 64), (1, 64, 1, 32)])
+def _scan_4d(q, k, v):
+    g = q.shape[2] // k.shape[2]
+    return blockwise_attention(q, jnp.repeat(k, g, axis=2),
+                               jnp.repeat(v, g, axis=2), q_block=128)
+
+
+def _qkv(rng, b, s, h, kh, hd, dtype):
+    return (jnp.array(rng.randn(b, s, h, hd), dtype),
+            jnp.array(rng.randn(b, s, kh, hd), dtype),
+            jnp.array(rng.randn(b, s, kh, hd), dtype))
+
+
+def _blocks(fwd, bwd, fused=True):
+    dq = {} if fused else {"block_q_dq": bwd, "block_kv_dq": bwd}
+    return splash.BlockSizes(block_q=fwd, block_kv=fwd, block_kv_compute=fwd,
+                             block_q_dkv=bwd, block_kv_dkv=bwd,
+                             block_kv_dkv_compute=bwd,
+                             use_fused_bwd_kernel=fused, **dq)
+
+
+# (batch, seq, query heads, kv heads, head dim): GQA groups 1 and 3, hd 64
+# and 128, S 256 and 512, batch 1 and 2
+SHAPES = [(2, 256, 4, 4, 64), (1, 256, 9, 3, 64), (2, 512, 6, 2, 128),
+          (1, 512, 2, 2, 128)]
+
+
+@pytest.mark.parametrize("b,s,h,kh,hd", SHAPES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(b, s, h, hd, dtype):
-    q = jnp.array(RNG.randn(b, s, h, hd), dtype)
-    k = jnp.array(RNG.randn(b, s, h, hd), dtype)
-    v = jnp.array(RNG.randn(b, s, h, hd), dtype)
-    out = ops.flash_attention(q, k, v, q_block=min(128, s), kv_block=min(128, s))
+def test_flash_attention_sweep(b, s, h, kh, hd, dtype):
+    q, k, v = _qkv(RNG, b, s, h, kh, hd, dtype)
+    out = fa.flash_attention(q, k, v)
     want = _attn_ref_4d(q, k, v)
     tol = 5e-6 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("qb,kb", [(64, 32), (128, 256), (32, 32)])
-def test_flash_attention_block_shapes(qb, kb):
-    b, s, h, hd = 1, 256, 2, 64
-    q = jnp.array(RNG.randn(b, s, h, hd), jnp.float32)
-    k = jnp.array(RNG.randn(b, s, h, hd), jnp.float32)
-    v = jnp.array(RNG.randn(b, s, h, hd), jnp.float32)
-    out = ops.flash_attention(q, k, v, q_block=qb, kv_block=kb)
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+def _grads(fn, q, k, v, ct):
+    return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * ct),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("b,s,h,kh,hd", SHAPES + [(2, 512, 9, 3, 64),
+                                                  (1, 256, 6, 2, 128)])
+def test_flash_attention_grads(b, s, h, kh, hd):
+    """dq, dk, dv of the kernel against the float32 oracle and the scan, bf16
+    operands: each within a relative norm of 1e-2 of the oracle's (bf16's
+    rounding is 2**-9), and no further from it than twice the scan is."""
+    rng = np.random.RandomState(b * 1000 + s + h + hd)
+    q, k, v = _qkv(rng, b, s, h, kh, hd, jnp.bfloat16)
+    ct = jnp.array(rng.randn(b, s, h, hd), jnp.float32)
+    f32 = lambda t: t.astype(jnp.float32)
+    want = _grads(lambda q, k, v: _attn_ref_4d(f32(q), f32(k), f32(v)), q, k, v, ct)
+    got = _grads(fa.flash_attention, q, k, v, ct)
+    scan = _grads(_scan_4d, q, k, v, ct)
+    for name, g, w, sc in zip("qkv", got, want, scan):
+        assert g.shape == w.shape and g.dtype == jnp.bfloat16, name
+        assert _rel(g, w) < 1e-2, (name, _rel(g, w))
+        assert _rel(g, w) < 2 * _rel(sc, w) + 1e-3, (name, _rel(g, w), _rel(sc, w))
+
+
+@pytest.mark.parametrize("blocks", [_blocks(128, 128), _blocks(256, 128, fused=False),
+                                    _blocks(128, 256, fused=False)])
+def test_flash_attention_block_shapes(blocks):
+    """Other tiles and the separate dq kernel: the same values and gradients
+    (float32, so the tiling's order of sums is all that differs)."""
+    b, s, h, kh, hd = 1, 256, 2, 1, 64
+    q, k, v = _qkv(RNG, b, s, h, kh, hd, jnp.float32)
+    out = fa.flash_attention(q, k, v, blocks=blocks)
     np.testing.assert_allclose(np.asarray(out), np.asarray(_attn_ref_4d(q, k, v)),
                                atol=1e-5, rtol=1e-5)
+    ct = jnp.array(RNG.randn(b, s, h, hd), jnp.float32)
+    got = _grads(partial(fa.flash_attention, blocks=blocks), q, k, v, ct)
+    want = _grads(_attn_ref_4d, q, k, v, ct)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4, rtol=1e-4)
 
 
 def test_flash_attention_non_causal():
-    b, s, h, hd = 1, 128, 2, 64
-    q = jnp.array(RNG.randn(b, s, h, hd), jnp.float32)
-    k = jnp.array(RNG.randn(b, s, h, hd), jnp.float32)
-    v = jnp.array(RNG.randn(b, s, h, hd), jnp.float32)
-    out = ops.flash_attention(q, k, v, causal=False)
+    b, s, h, kh, hd = 1, 128, 2, 1, 64
+    q, k, v = _qkv(RNG, b, s, h, kh, hd, jnp.float32)
+    out = fa.flash_attention(q, k, v, causal=False)
     want = _attn_ref_4d(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5, rtol=1e-5)
 
 
-@given(st.integers(1, 3), st.sampled_from([64, 128]), st.sampled_from([1, 2]),
-       st.sampled_from([32, 64]))
+@given(st.integers(1, 2), st.sampled_from([128, 256]), st.sampled_from([1, 3]),
+       st.sampled_from([64, 128]))
 @settings(max_examples=8, deadline=None)
-def test_flash_attention_property(b, s, h, hd):
-    rng = np.random.RandomState(b * 1000 + s + h + hd)
-    q = jnp.array(rng.randn(b, s, h, hd), jnp.float32)
-    k = jnp.array(rng.randn(b, s, h, hd), jnp.float32)
-    v = jnp.array(rng.randn(b, s, h, hd), jnp.float32)
-    out = ops.flash_attention(q, k, v, q_block=32, kv_block=32)
+def test_flash_attention_property(b, s, g, hd):
+    rng = np.random.RandomState(b * 1000 + s + g + hd)
+    q, k, v = _qkv(rng, b, s, 2 * g, 2, hd, jnp.float32)
+    out = fa.flash_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(_attn_ref_4d(q, k, v)),
                                atol=2e-5, rtol=2e-5)
 
 
 def test_flash_attention_softmax_invariance():
-    """Property: shifting all logits by a constant (scaling q) changes nothing
-    about the *uniform-value* case; softmax rows sum to one => output within the
-    convex hull of v rows."""
-    b, s, h, hd = 1, 128, 1, 64
-    q = jnp.array(RNG.randn(b, s, h, hd), jnp.float32)
-    k = jnp.array(RNG.randn(b, s, h, hd), jnp.float32)
-    v = jnp.ones((b, s, h, hd), jnp.float32) * 3.5
-    out = ops.flash_attention(q, k, v)
+    """Property: softmax rows sum to one, so with every value row equal the
+    output is that row, whatever the scores."""
+    b, s, h, kh, hd = 1, 128, 3, 1, 64
+    q, k, _ = _qkv(RNG, b, s, h, kh, hd, jnp.float32)
+    v = jnp.ones((b, s, kh, hd), jnp.float32) * 3.5
+    out = fa.flash_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), 3.5, atol=1e-5)
+
+
+def test_flash_attention_refuses_shapes_it_cannot_tile():
+    assert fa.block_sizes(4096, 64).block_q == 1024
+    assert fa.block_sizes(2560, 64).block_q_dkv == 512
+    assert fa.block_sizes(384, 128).block_q == 128
+    assert fa.block_sizes(200, 64) is None and fa.block_sizes(256, 32) is None
+    q, k, v = _qkv(RNG, 1, 64, 2, 1, 64, jnp.float32)
+    with pytest.raises(ValueError, match="cannot take"):
+        fa.flash_attention(q, k, v)
 
 
 @pytest.mark.parametrize("r,d", [(8, 128), (64, 576), (128, 2048), (5, 64)])

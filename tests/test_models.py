@@ -1,4 +1,5 @@
 """Architecture smoke tests (all 10, reduced configs) + semantic equivalences."""
+import collections
 import dataclasses
 
 import jax
@@ -10,6 +11,8 @@ from repro.configs import SHAPES, get_config, list_configs
 from repro.configs.base import ShapeConfig, shape_applicable
 from repro.models import build_model
 from repro.models import transformer as T
+
+from .helpers import run_devices
 
 ALL_ARCHS = list_configs()
 TRAIN_SHAPE = ShapeConfig("t", 64, 4, "train")
@@ -173,3 +176,113 @@ def test_gqa_repeat_semantics():
     out_m = attention(q, manual_k, manual_v, impl="naive")
     np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_m), atol=1e-6)
     assert not np.allclose(np.asarray(out_g), np.asarray(out))
+
+
+def _paths(fn, *specs):
+    """The attention paths one abstract trace of ``fn`` counts."""
+    from repro import telemetry as tm
+    before = collections.Counter(tm.ATTENTION_PATHS)
+    jax.eval_shape(fn, *specs)
+    return dict(tm.ATTENTION_PATHS - before)
+
+
+def _qkv_specs(b=2, s=256, h=9, kh=3, hd=64):
+    return tuple(jax.ShapeDtypeStruct((b, s, n, hd), jnp.bfloat16) for n in (h, kh, kh))
+
+
+def test_attention_path_on_the_cpu():
+    """The default traces the scan off a TPU; "pallas" forces the kernel."""
+    from repro.models.layers import attention
+    specs = _qkv_specs()
+    assert _paths(lambda q, k, v: attention(q, k, v), *specs) == {"scan": 1}
+    assert _paths(lambda q, k, v: attention(q, k, v, impl="blockwise"), *specs) == {"scan": 1}
+    assert _paths(lambda q, k, v: attention(q, k, v, impl="naive"), *specs) == {"naive": 1}
+    assert _paths(lambda q, k, v: attention(q, k, v, impl="pallas"), *specs) == {"fused": 1}
+    with pytest.raises(ValueError, match="query offset"):
+        _paths(lambda q, k, v: attention(q, k, v, impl="pallas", q_offset=8), *specs)
+    from repro import telemetry as tm
+    assert set(tm.ATTENTION_PATHS) <= set(tm.ATTENTION_PATH_NAMES)
+
+
+@pytest.mark.parametrize("kw,shape,want", [
+    ({}, {}, "fused"),
+    ({}, {"s": 4096}, "fused"),
+    ({}, {"s": 200}, "scan"),               # S not a multiple of a block
+    ({}, {"hd": 32}, "scan"),               # head dim not a multiple of 64
+    ({"q_offset": 8}, {}, "scan"),
+    ({"causal": False}, {}, "scan"),
+])
+def test_attention_path_on_a_tpu_backend(monkeypatch, kw, shape, want):
+    """The default's choice on a TPU backend, from the shapes alone (traced
+    abstractly, so nothing is compiled for the CPU)."""
+    from repro.models.layers import attention
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    specs = _qkv_specs(**shape)
+    assert _paths(lambda q, k, v: attention(q, k, v, **kw), *specs) == {want: 1}
+
+
+PER_SHARD = r'''
+import collections
+import jax, jax.numpy as jnp, numpy as np
+from repro import telemetry as tm
+from repro.launch.mesh import make_mesh
+from repro.models.layers import attention
+from repro.models.sharding import Sharder
+
+def paths(shd, b, h, kh):
+    before = collections.Counter(tm.ATTENTION_PATHS)
+    spec = lambda n: jax.ShapeDtypeStruct((b, 256, n, 64), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: attention(q, k, v, shd=shd), spec(h), spec(kh), spec(kh))
+    return dict(tm.ATTENTION_PATHS - before)
+
+dp = Sharder(make_mesh((4, 1), ("data", "model")))
+tp = Sharder(make_mesh((2, 2), ("data", "model")))
+backend = jax.default_backend
+jax.default_backend = lambda: "tpu"
+assert paths(dp, 4, 9, 3) == {"fused": 1}
+assert paths(dp, 2, 9, 3) == {"scan": 1}      # batch does not divide the data axis
+assert paths(tp, 2, 4, 2) == {"fused": 1}     # heads over tp
+assert paths(tp, 2, 9, 3) == {"scan": 1}      # context parallel: 9 heads on tp=2
+assert paths(tp, 2, 4, 1) == {"scan": 1}      # one kv head does not divide tp
+jax.default_backend = backend
+
+rng = np.random.RandomState(0)
+q = jnp.array(rng.randn(4, 256, 4, 64), jnp.float32)
+k, v = (jnp.array(rng.randn(4, 256, 2, 64), jnp.float32) for _ in range(2))
+ct = jnp.array(rng.randn(4, 256, 4, 64), jnp.float32)
+for shd in (dp, tp):
+    def run(impl):
+        f = lambda q, k, v: jnp.sum(attention(q, k, v, impl=impl, shd=shd) * ct)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))(q, k, v)
+    (lf, gf), (ls, gs) = run("pallas"), run("blockwise")
+    np.testing.assert_allclose(float(lf), float(ls), rtol=1e-5)
+    for a, b in zip(gf, gs):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+print("ALL_OK")
+'''
+
+
+def test_attention_per_shard_on_a_mesh():
+    """On four devices: the fused kernel runs per shard (batch over data,
+    heads over tp where H and K divide it) and matches the scan in value and
+    gradient; meshes it cannot be split over take the scan."""
+    assert "ALL_OK" in run_devices(PER_SHARD, 4)
+
+
+def test_model_loss_and_grad_fused_match_scan():
+    """A reduced smollm (hd 64, GQA 2:1) at S=128: loss and gradient with the
+    kernel forced (interpreted) against the scan."""
+    base = dataclasses.replace(get_config("smollm-135m").reduced(),
+                               n_heads=2, n_kv_heads=1)
+    assert base.head_dim == 64
+    shape = ShapeConfig("t", 128, 2, "train")
+    models = {impl: build_model(dataclasses.replace(base, attn_impl=impl))
+              for impl in ("pallas", "blockwise")}
+    params = models["blockwise"].init(jax.random.PRNGKey(0))
+    batch = models["blockwise"].make_batch(shape)
+    (lf, gf), (ls, gs) = (jax.jit(jax.value_and_grad(m.loss))(params, batch)
+                          for m in models.values())
+    assert abs(float(lf) - float(ls)) / float(ls) < 1e-3
+    for a, b in zip(jax.tree.leaves(gf), jax.tree.leaves(gs)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) <= 2e-2 * np.linalg.norm(b) + 1e-6

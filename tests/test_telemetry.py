@@ -138,6 +138,21 @@ def test_hlo_op_names_and_module_name():
     assert hlo_trace.op_names("  %copy.7 = f32[4]{0} copy(%p)") == {"copy.7": ""}
 
 
+def test_hlo_op_names_of_an_instruction_on_several_lines():
+    """A Pallas kernel's custom call prints its kernel metadata on lines of
+    its own, before its op_name: the op_name is still the instruction's."""
+    text = "\n".join([
+        "ENTRY %main.9 (p.1: bf16[4]) -> bf16[4] {",
+        '  %k.1 = bf16[4]{0} custom-call(%p.1), custom_call_target="tpu_custom_call", '
+        "frontend_attributes={kernel_metadata={",
+        '"xprof_metadata":"{\\"block_q\\": 512}"',
+        '}}, metadata={op_name="jit(f)/attention/pallas_call" stack_frame_id=2}',
+        "  ROOT %copy.2 = bf16[4]{0} copy(%k.1)",
+        "}"])
+    assert hlo_trace.op_names(text) == {"k.1": "jit(f)/attention/pallas_call",
+                                        "copy.2": ""}
+
+
 # ------------------------------------------------- spans in a CPU profile
 def _host_spans(logdir):
     from jax.profiler import ProfileData

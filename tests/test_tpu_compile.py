@@ -1,5 +1,6 @@
-"""Compile-only checks for a described TPU v5e: the main path's kernels and
-the gradient codec at smollm-135m's published widths.
+"""Compile-only checks for a described TPU v5e: the main path's kernels (the
+fused flash attention, forward and backward) and the gradient codec at
+smollm-135m's published widths.
 
 Nothing runs: each test lowers and compiles for a chip that is described, not
 attached, so a kernel the TPU compiler would refuse (block shapes off the
@@ -12,14 +13,19 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from repro import telemetry
+from repro.analysis import hlo_trace
 from repro.configs import get_config
 from repro.core.commplan import DEFAULT_BUCKET_BYTES
 from repro.kernels import bucket_codec as bc
-from repro.kernels import ops
+from repro.kernels import flash_attention as fa
 from repro.models import build_model
+from repro.models.layers import attention
+from repro.models.sharding import Sharder
 
 #: the ZeRO shard geometry of the four-chip explicit-DP step
 N_DP = 4
@@ -100,14 +106,58 @@ def test_adamw_shard_kernel_compiles_full_width(one_chip, smollm_leaves,
     assert "tpu_custom_call" in text
 
 
-def test_flash_attention_fwd_compiles_smollm_width(one_chip):
-    """The flash-attention forward at smollm-135m's train_4k widths (S=4096,
-    9 heads of 64) in bf16."""
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """The flash kernel built as a TPU process builds it (never interpreted)."""
+    monkeypatch.setattr(fa, "interpret_mode", lambda requested=None: False)
+
+
+def _kernel_scopes(text):
+    """The named scope of each Pallas kernel in a compiled module's text."""
+    names = hlo_trace.op_names(text)
+    calls = [ln.split("=")[0].replace("ROOT", "").strip().lstrip("%")
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    return {c: telemetry.scope_of(names.get(c, "")) for c in calls}
+
+
+def _smollm_qkv(sharding, batch=4):
+    """q, k, v at smollm-135m's train_4k widths: S=4096, 9 / 3 heads of 64."""
     cfg = get_config("smollm-135m")
-    hd = cfg.head_dim
-    assert (cfg.n_heads, hd) == (9, 64)
-    q, k, v = (_spec((1, 4096, cfg.n_heads, hd), jnp.bfloat16, one_chip)
-               for _ in range(3))
-    text = _hlo(lambda q, k, v: ops.flash_attention(q, k, v, interpret=False),
-                q, k, v)
-    assert "tpu_custom_call" in text
+    assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (9, 3, 64)
+    return tuple(_spec((batch, 4096, n, cfg.head_dim), jnp.bfloat16, sharding)
+                 for n in (cfg.n_heads, cfg.n_kv_heads, cfg.n_kv_heads))
+
+
+def _attention_loss(q, k, v, shd=None):
+    with telemetry.scope("attention"):
+        o = attention(q, k, v, impl="pallas", shd=shd)
+    return jnp.sum(o.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("pass_", ["forward", "backward"])
+def test_flash_attention_compiles_smollm_train_4k(one_chip, compiled_kernels,
+                                                  pass_):
+    """The fused kernel at the training cell's shapes, forward alone and
+    forward with backward; every kernel lies under the `attention` scope, so
+    the scope's device time counts it."""
+    args = _smollm_qkv(one_chip)
+    fn = (_attention_loss if pass_ == "forward"
+          else jax.grad(_attention_loss, argnums=(0, 1, 2)))
+    kernels = _kernel_scopes(_hlo(fn, *args))
+    assert len(kernels) == (1 if pass_ == "forward" else 2), kernels
+    assert set(kernels.values()) == {"attention"}, kernels
+
+
+def test_flash_attention_per_shard_compiles_four_chips(topo, compiled_kernels):
+    """Per shard on four described chips, batch over the data axis: forward
+    and backward with no collective."""
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    shd = Sharder(mesh)
+    args = _smollm_qkv(NamedSharding(mesh, P("data")), batch=8)
+    text = _hlo(jax.grad(lambda q, k, v: _attention_loss(q, k, v, shd),
+                         argnums=(0, 1, 2)), *args)
+    assert set(_kernel_scopes(text).values()) == {"attention"}
+    for collective in ("all-reduce", "all-gather", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text, collective
