@@ -16,7 +16,7 @@ class ModelConfig:
     d_ff: int
     vocab: int
     qkv_bias: bool = False
-    mlp: str = "swiglu"             # swiglu | gelu
+    mlp: str = "swiglu"             # swiglu | gelu | geglu (gated, exact GELU)
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
     # --- MoE ---
@@ -31,8 +31,13 @@ class ModelConfig:
     ssm_headdim: int = 64
     ssm_conv: int = 4
     ssm_chunk: int = 256
-    # --- hybrid (Zamba2-style: shared attention block every k SSM layers) ---
-    attn_every: int = 0
+    ssm_ngroups: int = 1            # B/C groups; head h reads group h // (H / G)
+    ssm_dt_min: float = 0.0         # dt clamped below after softplus (0: none)
+    # --- hybrid (Zamba2: shared attention blocks before some Mamba2 layers) ---
+    hybrid_layer_ids: Tuple[int, ...] = ()   # layers a shared block feeds
+    n_mem_blocks: int = 0           # shared blocks, applied in turn
+    adapter_rank: int = 0           # per-application LoRA on the block's MLP
+    attn_head_dim: int = 0          # 0: d_model // n_heads
     # --- modality frontends (stubs; see DESIGN.md Sec. 4) ---
     n_codebooks: int = 0            # audio: EnCodec codebooks
     n_img_tokens: int = 0           # vlm: precomputed patch embeddings per sample
@@ -52,7 +57,7 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // max(self.n_heads, 1)
+        return self.attn_head_dim or self.d_model // max(self.n_heads, 1)
 
     @property
     def d_inner(self) -> int:
@@ -67,7 +72,7 @@ class ModelConfig:
         d, f, L, V = self.d_model, self.d_ff, self.n_layers, self.vocab
         hd, H, K = self.head_dim, self.n_heads, self.n_kv_heads
         attn = d * H * hd + 2 * d * K * hd + H * hd * d      # q,k,v,o
-        if self.mlp == "swiglu":
+        if self.mlp in ("swiglu", "geglu"):
             mlp = 3 * d * f
         else:
             mlp = 2 * d * f
@@ -79,22 +84,25 @@ class ModelConfig:
         if self.family == "ssm":
             per_layer = self._ssm_layer_params()
         if self.family == "hybrid":
-            n_attn = L // max(self.attn_every, 1)
-            per_layer = self._ssm_layer_params()
-            emb = V * d
-            shared = attn + 3 * d * f + 2 * d + 2 * d * d  # one shared block + in-proj
-            return L * per_layer + shared + emb + (0 if self.tie_embeddings else V * d)
+            # shared blocks: q/k/v over [x, x_embed] (2d), o back to d, the
+            # gated MLP, two norms; per application a LoRA (d -> r -> 2f) on
+            # the MLP's input projection and a d x d output linear
+            ad = 2 * d
+            block = (ad * (H + 2 * K) * hd + H * hd * d + 3 * d * f + ad + d)
+            apps = len(self.hybrid_layer_ids) * (self.adapter_rank * (d + 2 * f) + d * d)
+            return (L * self._ssm_layer_params() + self.n_mem_blocks * block + apps
+                    + V * d + d + (0 if self.tie_embeddings else V * d))
         emb = V * d * (self.n_codebooks or 1)
         head = 0 if self.tie_embeddings else V * d * (self.n_codebooks or 1)
         return L * per_layer + emb + head
 
     def _ssm_layer_params(self) -> int:
-        d, di, N = self.d_model, self.d_inner, self.ssm_state
+        d, di, GN = self.d_model, self.d_inner, self.ssm_ngroups * self.ssm_state
         H = self.ssm_heads
-        in_proj = d * (2 * di + 2 * N + H)
-        conv = (di + 2 * N) * self.ssm_conv
+        in_proj = d * (2 * di + 2 * GN + H)
+        conv = (di + 2 * GN) * (self.ssm_conv + 1)            # weights and bias
         out = di * d
-        return in_proj + conv + out + 2 * H + di + 2 * d
+        return in_proj + conv + out + 3 * H + di + d
 
     def active_param_count(self) -> int:
         """Active params per token (MoE: only top-k + shared experts count)."""
@@ -125,7 +133,10 @@ class ModelConfig:
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_headdim=32 if self.ssm_state else 64,
             ssm_chunk=8,
-            attn_every=2 if self.attn_every else 0,
+            # hybrid: both shared blocks, each applied once, over 4 layers
+            hybrid_layer_ids=(1, 3) if self.hybrid_layer_ids else (),
+            adapter_rank=min(self.adapter_rank, 8),
+            attn_head_dim=2 * 128 // 4 if self.attn_head_dim else 0,
             n_img_tokens=8 if self.n_img_tokens else 0,
             q_block=16,
         )

@@ -11,10 +11,14 @@ recomputes the probabilities from the saved log-sum-exp, block by block.
 Matmul operands stay in the input dtype with float32 accumulation, except
 that the forward's P·V takes P and V in float32.
 
-The kernel applies no scale: 1/sqrt(hd) is folded into q, exactly for a
-power-of-two scale (hd 64, 256), else in float32 and rounded once to q's
-dtype.  Validated against ``ref.attention_ref`` in interpret mode on the
-CPU; compiled by Mosaic for a described v5e in tests/test_tpu_compile.py.
+The kernel applies no scale: the softmax scale (1/sqrt(hd) unless given)
+is folded into q, exactly for a power of two (hd 64, 256), else in float32
+and rounded once to q's dtype (Zamba2's (224 / 2) ** -0.5 costs that one
+rounding).  A head dim that is not a multiple of the 128 lanes (Zamba2's
+224) goes to the kernel as it is: at B 4, S 3584, 32 heads of 224 on a v5e
+its forward took 12.2 ms against 15.8 ms with q, k and v zero-padded to 256
+(PERF.md).  Validated against ``ref.attention_ref`` in interpret mode on
+the CPU; compiled by Mosaic for a described v5e in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -33,13 +37,16 @@ from .interpret import interpret_mode
 #: it serves forward and backward alike: at S=4096, hd 64 on a v5e, 1024 beat
 #: 512 in both (PERF.md, section 5)
 BLOCKS = (1024, 512, 256, 128)
+#: the TPU's lane width
+LANES = 128
 
 
 def block_sizes(seq: int, head_dim: int) -> Optional[splash.BlockSizes]:
     """The forward and backward tiles for a (seq, head_dim) problem, or None
-    where the kernel cannot take it (seq not a multiple of 128, head_dim
-    not a multiple of 64)."""
-    if head_dim % 64:
+    where the kernel cannot take it: seq not a multiple of 128, head_dim
+    neither a multiple of 64 nor one of 32 from a lane's width up (Zamba2's
+    224)."""
+    if head_dim % 64 and (head_dim % 32 or head_dim < LANES):
         return None
     blk = next((b for b in BLOCKS if seq % b == 0), None)
     if blk is None:
@@ -63,21 +70,24 @@ def _kernel(seq: int, groups: int, blocks: splash.BlockSizes, causal: bool,
             interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "blocks", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "blocks", "interpret",
+                                             "scale"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     blocks: Optional[splash.BlockSizes] = None,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
+                    interpret: Optional[bool] = None,
+                    scale: Optional[float] = None) -> jnp.ndarray:
     """q: (B, S, H, hd); k, v: (B, S, K, hd) with K | H.  Returns (B, S, H, hd).
 
     ``blocks`` defaults to ``block_sizes(S, hd)``; ``interpret`` resolves
-    through ``interpret_mode`` (compiled on a TPU, interpreted elsewhere)."""
+    through ``interpret_mode`` (compiled on a TPU, interpreted elsewhere);
+    ``scale`` multiplies the scores (default 1/sqrt(hd))."""
     b, s, h, hd = q.shape
     kh = k.shape[2]
     g = h // kh
     blocks = blocks or block_sizes(s, hd)
     if blocks is None or h % kh or k.shape[1] != s:
         raise ValueError(f"flash_attention cannot take q {q.shape}, k {k.shape}")
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     if scale == 2.0 ** round(math.log2(scale)):     # exact in any float dtype
         q = q * jnp.asarray(scale, q.dtype)
     else:

@@ -7,9 +7,9 @@ import jax
 import jax.numpy as jnp
 
 
-def attention_ref(q, k, v, *, causal: bool = True) -> jnp.ndarray:
-    """q, k, v: (BH, S, hd)."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+def attention_ref(q, k, v, *, causal: bool = True, scale=None) -> jnp.ndarray:
+    """q, k, v: (BH, S, hd); scores scaled by ``scale`` (default 1/sqrt(hd))."""
+    scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     if causal:
         sq, sk = q.shape[1], k.shape[1]

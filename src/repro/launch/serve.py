@@ -1,11 +1,16 @@
 """Production serving launcher (batched prefill + sequence-sharded decode).
 
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m [--reduced] \
-      --batch 4 --prompt-len 16 --new-tokens 32 [--mesh 2x4] [--seq-axes model,data]
+      --batch 4 --prompt-len 16 --new-tokens 32 [--mesh 2x4] [--seq-axes model,data] \
+      [--layers N]
+
+``--layers N`` serves the first N layers alone (a pipeline's first stage;
+a hybrid keeps the shared-block applications among them), at every width.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
 
@@ -33,11 +38,17 @@ def main(argv=None):
     ap.add_argument("--seq-axes", default=None,
                     help='comma list remapping the KV-cache "seq" sharding, '
                          'e.g. "model,data" for batch=1 long-context decode')
+    ap.add_argument("--layers", type=int, default=None,
+                    help="serve the first N layers only")
     args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers:
+        cfg = dataclasses.replace(
+            cfg, n_layers=args.layers,
+            hybrid_layer_ids=tuple(i for i in cfg.hybrid_layer_ids if i < args.layers))
     mesh = parse_mesh(args.mesh) if args.mesh else make_host_mesh()
     max_seq = args.prompt_len + args.new_tokens + 8
     server = BatchedServer(cfg, max_seq=max_seq, batch_size=args.batch, mesh=mesh)

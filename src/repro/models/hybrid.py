@@ -1,162 +1,182 @@
-"""Zamba2-style hybrid: Mamba2 backbone + ONE shared transformer block applied
-every `attn_every` SSM layers (weights shared across applications; the block input
-is concat([x, x_embed]) projected 2D->D, following the Zamba design).
+"""Zamba2 hybrid (arXiv:2411.15242; ``Zamba2ForCausalLM`` of HF transformers):
+a Mamba2 backbone whose layers in ``hybrid_layer_ids`` first apply one of
+``n_mem_blocks`` shared transformer blocks, in turn.
 
-Layout: G = n_layers // attn_every groups of [attn_every mamba layers + shared
-block], plus R = n_layers - G*attn_every trailing mamba layers (81 = 13*6 + 3 for
-zamba2-7b).  The shared block's KV cache is (G, B, S, K, hd) — sequence-sharded
-for long-context decode (the paper-aligned path; DESIGN.md Sec. 5).
+With x0 the token embeddings and D the model width, for each layer l:
+
+  if l is the j-th entry of hybrid_layer_ids (block b = j mod n_mem_blocks):
+      u = RMSNorm(concat[x, x0]; in_norm[b])               # 2D wide
+      q, k, v = u·Wq[b], u·Wk[b], u·Wv[b]; RoPE on q, k (rotate-half, hd)
+      a = causal_softmax(q·kᵀ · (hd / 2) ** -0.5) · v · Wo[b]     # back to D
+      m = RMSNorm(a; ff_norm[b])                           # no residual inside
+      g, up = m·W1[b] + (m·A[j])·B1[j], m·W3[b] + (m·A[j])·B3[j]   # LoRA, rank r
+      T = ((gelu_erf(g) * up)·W2[b])·Lin[j]                # Lin[j]: D x D
+  else:
+      T = 0
+  x = x + Mamba2_l(x + T)      # T enters the mixer's input (its norm), not x
+
+then the final RMSNorm and the (tied) unembedding.  Parameters: the Mamba2
+layers stacked (L, ...), the shared blocks stacked (n_mem_blocks, ...), the
+per-application adapter and linear stacked (len(hybrid_layer_ids), ...).
+
+Departures from the published code: none in the equations; the Mamba2 dt is
+clamped below at ``ssm_dt_min`` after the softplus, as HF's torch path does
+(its CUDA path leaves dt unclamped).
+
+Execution: the layers run as runs of a ``lax.scan`` between consecutive
+hybrid layers, each over its layer indices with the stacked parameters and
+Mamba2 states indexed inside the loop (no copy of a weight slice); the shared
+block before a run adds T to the run's first layer.  Caches: the Mamba2 conv
+and SSM states of every layer (L, B, ...) beside one (B, S, K, hd) key and
+value cache per application.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from ..configs.base import ModelConfig
 from .layers import apply_rope, attention, decode_attention, mlp, rms_norm
 from .mamba2 import mamba_block, ssm_param_defs
 from .sharding import Sharder
 
 
+def attn_scale(cfg: ModelConfig) -> float:
+    """Zamba2's softmax scale: the head dim is twice the model's per-head
+    width (the block reads [x, x0]), and the scores take (hd / 2) ** -0.5."""
+    return (cfg.head_dim / 2) ** -0.5
+
+
 def hybrid_param_defs(cfg: ModelConfig) -> Dict:
     D, F, V = cfg.d_model, cfg.d_ff, cfg.vocab
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    G = cfg.n_layers // cfg.attn_every
-    R = cfg.n_layers - G * cfg.attn_every
+    NB, A, r = cfg.n_mem_blocks, len(cfg.hybrid_layer_ids), cfg.adapter_rank
     defs = {
         "emb": ((V, D), ("vocab", None)),
-        "mamba": ssm_param_defs(cfg, n_layers=G * cfg.attn_every),
-        "shared": {
-            "in_proj": ((2 * D, D), ("fsdp", None)),
-            "ln_in": ((2 * D,), (None,)),
-            "ln1": ((D,), (None,)),
-            "wq": ((D, H * hd), ("fsdp", "tp")),
-            "wk": ((D, K * hd), ("fsdp", "tp")),
-            "wv": ((D, K * hd), ("fsdp", "tp")),
-            "wo": ((H * hd, D), ("tp", "fsdp")),
-            "ln2": ((D,), (None,)),
-            "mlp": {"w1": ((D, F), ("fsdp", "tp")), "w2": ((F, D), ("tp", "fsdp"))},
-            "out_proj": ((D, D), ("fsdp", None)),
+        "mamba": ssm_param_defs(cfg),
+        "blocks": {
+            "in_norm": ((NB, 2 * D), (None, None)),
+            "wq": ((NB, 2 * D, H * hd), (None, "fsdp", "tp")),
+            "wk": ((NB, 2 * D, K * hd), (None, "fsdp", "tp")),
+            "wv": ((NB, 2 * D, K * hd), (None, "fsdp", "tp")),
+            "wo": ((NB, H * hd, D), (None, "tp", "fsdp")),
+            "ff_norm": ((NB, D), (None, None)),
+            "mlp": {"w1": ((NB, D, F), (None, "fsdp", "tp")),
+                    "w3": ((NB, D, F), (None, "fsdp", "tp")),
+                    "w2": ((NB, F, D), (None, "tp", "fsdp"))},
+        },
+        "adapters": {
+            "a": ((A, D, r), (None, "fsdp", None)),
+            "b1": ((A, r, F), (None, None, "tp")),
+            "b3": ((A, r, F), (None, None, "tp")),
+            "lin": ((A, D, D), (None, "fsdp", None)),
         },
         "ln_f": ((D,), (None,)),
-        "head": ((V, D), ("vocab", None)),
     }
-    if R:
-        defs["extra"] = ssm_param_defs(cfg, n_layers=R)
+    if not cfg.tie_embeddings:
+        defs["head"] = ((V, D), ("vocab", None))
     return defs
 
 
-def shared_block(x, x0, sp, cfg: ModelConfig, shd: Optional[Sharder], positions,
-                 kv: Optional[Tuple] = None, pos=None):
-    """The shared attention+MLP block.  x0: token embeddings (Zamba concat trick)."""
+def _index(tree, i):
+    return jax.tree.map(lambda a: a[i], tree)
+
+
+def shared_block(x, x0, bp, ap, cfg: ModelConfig, shd: Optional[Sharder],
+                 positions, kv=None, pos=None):
+    """One application of a shared block (weights ``bp``) with its adapter and
+    output linear (``ap``).  Returns (T, new (k, v) cache or None)."""
     B, S, D = x.shape
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    h = rms_norm(jnp.concatenate([x, x0], axis=-1), sp["ln_in"])
-    h = jnp.einsum("bse,ed->bsd", h, sp["in_proj"])
-    a_in = rms_norm(h, sp["ln1"])
-    q = jnp.einsum("bsd,de->bse", a_in, sp["wq"]).reshape(B, S, H, hd)
-    k = jnp.einsum("bsd,de->bse", a_in, sp["wk"]).reshape(B, S, K, hd)
-    v = jnp.einsum("bsd,de->bse", a_in, sp["wv"]).reshape(B, S, K, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    new_kv = None
-    if kv is not None:
-        kc, vc = kv
-        if pos is None:
-            kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype), (0, 0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype), (0, 0, 0, 0))
-            o = attention(q, k, v, impl=cfg.attn_impl, q_block=cfg.q_block, shd=shd)
+    scale = attn_scale(cfg)
+    with telemetry.scope("attention"):
+        u = rms_norm(jnp.concatenate([x, x0], axis=-1), bp["in_norm"])
+        q = jnp.einsum("bse,ef->bsf", u, bp["wq"]).reshape(B, S, H, hd)
+        k = jnp.einsum("bse,ef->bsf", u, bp["wk"]).reshape(B, S, K, hd)
+        v = jnp.einsum("bse,ef->bsf", u, bp["wv"]).reshape(B, S, K, hd)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        new_kv = None
+        if kv is not None and pos is not None:      # decode: one token at pos
+            kc, vc = (jax.lax.dynamic_update_slice(c, t.astype(c.dtype), (0, pos, 0, 0))
+                      for c, t in zip(kv, (k, v)))
+            o = decode_attention(q, kc, vc, pos, shd=shd, scale=scale)
+            new_kv = (kc, vc)
         else:
-            kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype), (0, pos, 0, 0))
-            vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype), (0, pos, 0, 0))
-            if shd is not None:
-                kc = shd.constrain(kc, "batch", "seq", None, None)
-                vc = shd.constrain(vc, "batch", "seq", None, None)
-            o = decode_attention(q, kc, vc, pos, shd=shd)
-        new_kv = (kc, vc)
-    else:
-        o = attention(q, k, v, impl=cfg.attn_impl, q_block=cfg.q_block, shd=shd)
-    h = h + jnp.einsum("bse,ed->bsd", o.reshape(B, S, H * hd), sp["wo"])
-    h = h + mlp(rms_norm(h, sp["ln2"]), sp["mlp"], cfg.mlp, shd)
-    return x + jnp.einsum("bsd,de->bse", h, sp["out_proj"]), new_kv
+            if kv is not None:                      # prefill: the prompt's keys
+                new_kv = tuple(jax.lax.dynamic_update_slice(c, t.astype(c.dtype),
+                                                            (0, 0, 0, 0))
+                               for c, t in zip(kv, (k, v)))
+            o = attention(q, k, v, impl=cfg.attn_impl, q_block=cfg.q_block,
+                          shd=shd, scale=scale)
+        a = jnp.einsum("bse,ed->bsd", o.reshape(B, S, H * hd), bp["wo"])
+    with telemetry.scope("mlp"):
+        f = mlp(rms_norm(a, bp["ff_norm"]), bp["mlp"], cfg.mlp, shd, adapter=ap)
+    with telemetry.scope("adapter"):
+        t = jnp.einsum("bsd,de->bse", f, ap["lin"])
+    return t, new_kv
 
 
-def _group_tree(tree, G: int, M: int):
-    return jax.tree.map(lambda a: a.reshape(G, M, *a.shape[1:]), tree)
+def _mamba_run(x, t, mp, states, first: int, last: int, cfg: ModelConfig,
+               shd: Optional[Sharder], decode: bool):
+    """Mamba2 layers first..last-1 over x, T (or None) added to the first
+    one's input.  ``states`` (L, B, ...) conv / ssm, or None: decode reads and
+    writes layer l's, prefill writes them."""
+
+    def body(carry, l):
+        x, st = carry
+        h = x if t is None else jnp.where(l == first, x + t, x)
+        out, new = mamba_block(h, _index(mp, l), cfg, shd,
+                               _index(st, l) if decode else None)
+        if st is not None:
+            st = jax.tree.map(lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                a, n.astype(a.dtype), l, 0), st, new)
+        x = x + out
+        if shd is not None:
+            x = shd.constrain(x, "batch", None, None)
+        return (x, st), None
+
+    if cfg.remat == "block" and states is None:
+        body = jax.checkpoint(body)
+    (x, states), _ = jax.lax.scan(body, (x, states), jnp.arange(first, last))
+    return x, states
+
+
+def hybrid_trunk(params, x0, cfg: ModelConfig, shd: Optional[Sharder], positions,
+                 cache: Optional[Dict] = None, pos=None):
+    """The layers and the final norm.  cache None: training / scoring;
+    pos None: prefill into ``cache``; else decode one token at ``pos``.
+    Returns (x, new cache or None)."""
+    ids = cfg.hybrid_layer_ids
+    bounds = [0, *ids, cfg.n_layers]
+    states = None if cache is None else {"conv": cache["conv"], "ssm": cache["ssm"]}
+    kvs: List = []
+
+    def block(x, bp, ap, kv):
+        return shared_block(x, x0, bp, ap, cfg, shd, positions, kv, pos)
+
+    if cfg.remat == "block" and cache is None:
+        block = jax.checkpoint(block)
+    x, t = x0, None
+    for g in range(len(bounds) - 1):
+        if g:
+            j = g - 1
+            kv = None if cache is None else (cache["k"][j], cache["v"][j])
+            t, new_kv = block(x, _index(params["blocks"], j % cfg.n_mem_blocks),
+                              _index(params["adapters"], j), kv)
+            kvs.append(new_kv)
+        if bounds[g + 1] > bounds[g]:
+            x, states = _mamba_run(x, t, params["mamba"], states, bounds[g],
+                                   bounds[g + 1], cfg, shd, pos is not None)
+    x = rms_norm(x, params["ln_f"])
+    if cache is None:
+        return x, None
+    return x, {**states, "k": [kv[0] for kv in kvs], "v": [kv[1] for kv in kvs]}
 
 
 def hybrid_forward(params, x0, cfg: ModelConfig, shd: Optional[Sharder], positions):
     """Training/scoring trunk.  x0: (B, S, D) embeddings."""
-    G, M = cfg.n_layers // cfg.attn_every, cfg.attn_every
-    grouped = _group_tree(params["mamba"], G, M)
-    sp = params["shared"]
-
-    def inner(c, lp):
-        out, _ = mamba_block(c, lp, cfg, shd)
-        return c + out, None
-
-    def group_body(c, gp):
-        h, _ = jax.lax.scan(inner, c, gp)
-        h, _ = shared_block(h, x0, sp, cfg, shd, positions)
-        if shd is not None:
-            h = shd.constrain(h, "batch", None, None)
-        return h, None
-
-    if cfg.remat == "block":
-        group_body = jax.checkpoint(group_body)
-    x, _ = jax.lax.scan(group_body, x0, grouped)
-    if "extra" in params:
-        body = jax.checkpoint(inner) if cfg.remat == "block" else inner
-        x, _ = jax.lax.scan(body, x, params["extra"])
-    return rms_norm(x, params["ln_f"])
-
-
-def hybrid_forward_cached(params, x0, cfg: ModelConfig, shd, positions, cache, pos=None):
-    """Prefill (pos None) / decode (pos scalar) with states.
-
-    cache = {"mamba": {"conv","ssm"} leading dim G*M, "extra": same (R),
-             "k","v": (G, B, S, K, hd)}  (mamba states present only in decode).
-    """
-    G, M = cfg.n_layers // cfg.attn_every, cfg.attn_every
-    grouped = _group_tree(params["mamba"], G, M)
-    sp = params["shared"]
-    decode = pos is not None
-
-    def inner(c, xs):
-        lp, st = xs
-        out, new_st = mamba_block(c, lp, cfg, shd, st)
-        return c + out, new_st
-
-    def inner_prefill(c, lp):
-        out, st = mamba_block(c, lp, cfg, shd)
-        return c + out, st
-
-    def group_body(c, xs):
-        if decode:
-            gp, gst, kc, vc = xs
-            h, new_st = jax.lax.scan(inner, c, (gp, gst))
-        else:
-            gp, kc, vc = xs
-            h, new_st = jax.lax.scan(inner_prefill, c, gp)
-        h, (kc, vc) = shared_block(h, x0, sp, cfg, shd, positions, (kc, vc), pos)
-        return h, (new_st, kc, vc)
-
-    if decode:
-        gstates = _group_tree(cache["mamba"], G, M)
-        x, (new_states, kcs, vcs) = jax.lax.scan(
-            group_body, x0, (grouped, gstates, cache["k"], cache["v"]))
-    else:
-        x, (new_states, kcs, vcs) = jax.lax.scan(
-            group_body, x0, (grouped, cache["k"], cache["v"]))
-    new_cache = {"mamba": jax.tree.map(lambda a: a.reshape(G * M, *a.shape[2:]), new_states),
-                 "k": kcs, "v": vcs}
-
-    if "extra" in params:
-        if decode:
-            x, new_extra = jax.lax.scan(inner, x, (params["extra"], cache["extra"]))
-        else:
-            x, new_extra = jax.lax.scan(inner_prefill, x, params["extra"])
-        new_cache["extra"] = new_extra
-    return rms_norm(x, params["ln_f"]), new_cache
+    return hybrid_trunk(params, x0, cfg, shd, positions)[0]

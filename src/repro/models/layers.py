@@ -81,10 +81,16 @@ def _repeat_kv(k: jnp.ndarray, n_rep: int) -> jnp.ndarray:
     return jnp.broadcast_to(k[:, :, :, None, :], (b, s, kh, n_rep, hd)).reshape(b, s, kh * n_rep, hd)
 
 
+def _scale(scale: Optional[float], hd: int) -> float:
+    return 1.0 / math.sqrt(hd) if scale is None else scale
+
+
 def naive_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
-                    shd: Optional[Sharder] = None) -> jnp.ndarray:
-    """q: (B, Sq, H, hd); k, v: (B, Sk, H, hd) (kv already repeated to H)."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+                    shd: Optional[Sharder] = None,
+                    scale: Optional[float] = None) -> jnp.ndarray:
+    """q: (B, Sq, H, hd); k, v: (B, Sk, H, hd) (kv already repeated to H).
+    Scores are scaled by ``scale`` (default 1/sqrt(hd))."""
+    scale = _scale(scale, q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
@@ -98,7 +104,8 @@ def naive_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
 
 def blockwise_attention(q, k, v, *, causal: bool = True, q_block: int = 256,
                         q_offset: int = 0, shd: Optional[Sharder] = None,
-                        context_parallel: bool = False) -> jnp.ndarray:
+                        context_parallel: bool = False,
+                        scale: Optional[float] = None) -> jnp.ndarray:
     """Memory-bounded attention: scan over query blocks (score tile q_block x Sk).
 
     context_parallel=True shards the *within-block* query dim over the `model`
@@ -109,9 +116,10 @@ def blockwise_attention(q, k, v, *, causal: bool = True, q_block: int = 256,
     sk = k.shape[1]
     qb = min(q_block, sq)
     if sq % qb != 0:
-        return naive_attention(q, k, v, causal=causal, q_offset=q_offset, shd=shd)
+        return naive_attention(q, k, v, causal=causal, q_offset=q_offset, shd=shd,
+                               scale=scale)
     nb = sq // qb
-    scale = 1.0 / math.sqrt(hd)
+    scale = _scale(scale, hd)
     qr = q.reshape(b, nb, qb, h, hd).transpose(1, 0, 2, 3, 4)   # (nb, B, qb, H, hd)
     kpos = jnp.arange(sk)
 
@@ -138,7 +146,8 @@ def blockwise_attention(q, k, v, *, causal: bool = True, q_block: int = 256,
     return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, hd)
 
 
-def decode_attention(q, k_cache, v_cache, pos, *, shd: Optional[Sharder] = None) -> jnp.ndarray:
+def decode_attention(q, k_cache, v_cache, pos, *, shd: Optional[Sharder] = None,
+                     scale: Optional[float] = None) -> jnp.ndarray:
     """One-token attention against a (possibly sequence-sharded) KV cache.
 
     q: (B, 1, H, hd); caches: (B, S, K, hd); pos: scalar index of the current token
@@ -157,7 +166,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, shd: Optional[Sharder] = None)
     if shd is not None:
         k_cache = shd.constrain(k_cache, "batch", "seq", None, None)
         v_cache = shd.constrain(v_cache, "batch", "seq", None, None)
-    scale = 1.0 / math.sqrt(hd)
+    scale = _scale(scale, hd)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k_cache,
                         preferred_element_type=jnp.float32) * scale  # (B,K,G,1,S)
     mask = (jnp.arange(s) <= pos)[None, None, None, None, :]
@@ -185,13 +194,15 @@ def _fused_specs(q, k, shd: Optional[Sharder]):
             shd.spec(("batch", None, heads, None), k.shape))
 
 
-def _fused(q, k, v, *, causal: bool, shd: Optional[Sharder]):
+def _fused(q, k, v, *, causal: bool, shd: Optional[Sharder],
+           scale: Optional[float]):
     specs = _fused_specs(q, k, shd)
     if specs is None:
-        return fa.flash_attention(q, k, v, causal=causal)
+        return fa.flash_attention(q, k, v, causal=causal, scale=scale)
     from jax import shard_map
     q_spec, kv_spec = specs
-    return shard_map(partial(fa.flash_attention, causal=causal), mesh=shd.mesh,
+    return shard_map(partial(fa.flash_attention, causal=causal, scale=scale),
+                     mesh=shd.mesh,
                      in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec,
                      check_vma=False)(q, k, v)
 
@@ -213,8 +224,10 @@ def _fused_fits(q, k, *, causal: bool, q_offset: int,
 
 def attention(q, k, v, *, impl: str = "flash", causal: bool = True,
               q_block: int = 256, q_offset: int = 0,
-              shd: Optional[Sharder] = None) -> jnp.ndarray:
-    """Dispatch over implementations; kv is (B, S, K, hd) with K | H.
+              shd: Optional[Sharder] = None,
+              scale: Optional[float] = None) -> jnp.ndarray:
+    """Dispatch over implementations; kv is (B, S, K, hd) with K | H; scores
+    scaled by ``scale`` (default 1/sqrt(hd)).
 
     The fused kernel takes the K kv heads as they are; the scan and naive
     paths repeat them to H.  Each call counts its path in
@@ -228,7 +241,7 @@ def attention(q, k, v, *, impl: str = "flash", causal: bool = True,
         if q_offset:
             raise ValueError("the fused kernel takes no query offset")
         telemetry.ATTENTION_PATHS["fused"] += 1
-        return _fused(q, k, v, causal=causal, shd=shd)
+        return _fused(q, k, v, causal=causal, shd=shd, scale=scale)
     h = q.shape[2]
     kh = k.shape[2]
     k = _repeat_kv(k, h // kh)
@@ -248,20 +261,33 @@ def attention(q, k, v, *, impl: str = "flash", causal: bool = True,
         v = shd.constrain(v, "batch", "seq", None, None)
     if impl == "naive":
         telemetry.ATTENTION_PATHS["naive"] += 1
-        return naive_attention(q, k, v, causal=causal, q_offset=q_offset, shd=shd)
+        return naive_attention(q, k, v, causal=causal, q_offset=q_offset, shd=shd,
+                               scale=scale)
     telemetry.ATTENTION_PATHS["scan"] += 1
     return blockwise_attention(q, k, v, causal=causal, q_block=q_block,
                                q_offset=q_offset, shd=shd,
-                               context_parallel=False)
+                               context_parallel=False, scale=scale)
 
 
 def mlp(x: jnp.ndarray, params: dict, kind: str = "swiglu",
-        shd: Optional[Sharder] = None) -> jnp.ndarray:
-    """swiglu: silu(x@w1) * (x@w3) @ w2;  gelu: gelu(x@w1) @ w2."""
+        shd: Optional[Sharder] = None,
+        adapter: Optional[dict] = None) -> jnp.ndarray:
+    """swiglu: silu(x@w1) * (x@w3) @ w2;  geglu: the same with the exact
+    (erf) GELU;  gelu: gelu(x@w1) @ w2 (tanh approximation).
+
+    ``adapter`` {"a": (d, r), "b1": (r, f), "b3": (r, f)} adds a low-rank
+    term to a gated kind's input projections: x@w1 + (x@a)@b1 and
+    x@w3 + (x@a)@b3, under the ``adapter`` scope."""
     h = jnp.einsum("...d,df->...f", x, params["w1"])
-    if kind == "swiglu":
+    if kind in ("swiglu", "geglu"):
         g = jnp.einsum("...d,df->...f", x, params["w3"])
-        h = jax.nn.silu(h) * g
+        if adapter is not None:
+            with telemetry.scope("adapter"):
+                u = jnp.einsum("...d,dr->...r", x, adapter["a"])
+                h = h + jnp.einsum("...r,rf->...f", u, adapter["b1"])
+                g = g + jnp.einsum("...r,rf->...f", u, adapter["b3"])
+        act = jax.nn.silu if kind == "swiglu" else partial(jax.nn.gelu, approximate=False)
+        h = act(h) * g
     else:
         h = jax.nn.gelu(h)
     if shd is not None:

@@ -26,18 +26,25 @@ from .. import telemetry
 from .layers import rms_norm
 from .sharding import Sharder
 
-NGROUPS = 1  # B/C projection groups (Mamba2 default 1 group broadcast over heads)
+#: B/C projection groups by default (Mamba2's one group broadcast over every
+#: head); a configuration's own count is ``ModelConfig.ssm_ngroups``
+NGROUPS = ModelConfig.ssm_ngroups
+
+
+def conv_dim(cfg: ModelConfig) -> int:
+    """Channels of the causal conv (and its decode state): x, B and C."""
+    return cfg.d_inner + 2 * cfg.ssm_ngroups * cfg.ssm_state
 
 
 def ssm_param_defs(cfg: ModelConfig, n_layers: Optional[int] = None) -> Dict:
     L = n_layers if n_layers is not None else cfg.n_layers
     D, Di, N, H = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-    conv_dim = Di + 2 * NGROUPS * N
+    GN = cfg.ssm_ngroups * N
     return {
         "ln": ((L, D), (None, None)),
-        "in_proj": ((L, D, 2 * Di + 2 * NGROUPS * N + H), (None, "fsdp", "tp")),
-        "conv_w": ((L, cfg.ssm_conv, conv_dim), (None, None, "tp")),
-        "conv_b": ((L, conv_dim), (None, "tp")),
+        "in_proj": ((L, D, 2 * Di + 2 * GN + H), (None, "fsdp", "tp")),
+        "conv_w": ((L, cfg.ssm_conv, conv_dim(cfg)), (None, None, "tp")),
+        "conv_b": ((L, conv_dim(cfg)), (None, "tp")),
         "A_log": ((L, H), (None, "tp")),
         "dt_bias": ((L, H), (None, "tp")),
         "D_skip": ((L, H), (None, "tp")),
@@ -151,12 +158,17 @@ def _causal_conv(xBC, w, bias, conv_state=None):
 def mamba_block(x, lp, cfg: ModelConfig, shd: Optional[Sharder],
                 state: Optional[Dict] = None):
     """One Mamba2 block.  x: (B, S, D).  state (decode): {"conv": (B,K-1,Cdim),
-    "ssm": (B,H,P,N)}.  Returns (out, new_state)."""
+    "ssm": (B,H,P,N)}.  Returns (out, new_state).
+
+    With G = ``cfg.ssm_ngroups`` groups, head h reads B and C of group
+    h // (H / G), and the gated RMSNorm of y * silu(z) normalises each
+    group's Di / G channels apart."""
     Bsz, S, D = x.shape
     Di, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_headdim
+    G = cfg.ssm_ngroups
     h = rms_norm(x, lp["ln"], fast=cfg.fast_norm)
     zxbcdt = jnp.einsum("bsd,de->bse", h, lp["in_proj"])
-    z, xin, BC, dt = jnp.split(zxbcdt, [Di, 2 * Di, 2 * Di + 2 * NGROUPS * N], axis=-1)
+    z, xin, BC, dt = jnp.split(zxbcdt, [Di, 2 * Di, 2 * Di + 2 * G * N], axis=-1)
     xBC = jnp.concatenate([xin, BC], axis=-1)                       # (B,S,Di+2gN)
     if shd is not None:
         xBC = shd.constrain(xBC, "batch", None, "tp")
@@ -166,12 +178,14 @@ def mamba_block(x, lp, cfg: ModelConfig, shd: Optional[Sharder],
     else:
         xBC, new_conv = _causal_conv(xBC, lp["conv_w"], lp["conv_b"], state["conv"])
 
-    xs, Bmat, Cmat = jnp.split(xBC, [Di, Di + NGROUPS * N], axis=-1)
+    xs, Bmat, Cmat = jnp.split(xBC, [Di, Di + G * N], axis=-1)
     xs = xs.reshape(Bsz, S, H, P)
-    Bmat = Bmat.reshape(Bsz, S, NGROUPS, N)
-    Cmat = Cmat.reshape(Bsz, S, NGROUPS, N)
+    Bmat = Bmat.reshape(Bsz, S, G, N)
+    Cmat = Cmat.reshape(Bsz, S, G, N)
     A = -jnp.exp(lp["A_log"].astype(jnp.float32))                   # (H,)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])    # (B,S,H)
+    if cfg.ssm_dt_min:
+        dt = jnp.maximum(dt, cfg.ssm_dt_min)
 
     if state is None:
         chunk = min(cfg.ssm_chunk, S)
@@ -183,7 +197,7 @@ def mamba_block(x, lp, cfg: ModelConfig, shd: Optional[Sharder],
     else:
         # O(1) decode: single-step recurrence
         da = jnp.exp(dt[:, 0] * A)                                  # (B,H)
-        rep = H // NGROUPS
+        rep = H // G
         Bf = jnp.repeat(Bmat[:, 0], rep, axis=1).astype(jnp.float32)
         Cf = jnp.repeat(Cmat[:, 0], rep, axis=1).astype(jnp.float32)
         upd = jnp.einsum("bh,bhp,bhn->bhpn", dt[:, 0], xs[:, 0].astype(jnp.float32), Bf)
@@ -192,8 +206,9 @@ def mamba_block(x, lp, cfg: ModelConfig, shd: Optional[Sharder],
 
     y = (y.astype(jnp.float32) + xs.astype(jnp.float32) * lp["D_skip"][None, None, :, None])
     y = y.reshape(Bsz, S, Di).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), lp["gate_ln"],
-                 fast=cfg.fast_norm)
+    y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+    y = rms_norm(y.reshape(Bsz, S, G, Di // G), lp["gate_ln"].reshape(G, Di // G),
+                 fast=cfg.fast_norm).reshape(Bsz, S, Di)
     out = jnp.einsum("bse,ed->bsd", y, lp["out_proj"])
     # prefill also returns resumable states (conv tail + final ssm state)
     new_state = {"conv": new_conv, "ssm": new_ssm}

@@ -125,49 +125,29 @@ class Model:
         S = shape.seq_len
         K, hd = c.n_kv_heads, c.head_dim
         mk = lambda sh, dt=PARAM_DTYPE: jax.ShapeDtypeStruct(sh, dt)
-        if c.family == "ssm":
+        if c.family in ("ssm", "hybrid"):
             L = c.n_layers
-            return {
-                "conv": mk((L, B, c.ssm_conv - 1, c.d_inner + 2 * M.NGROUPS * c.ssm_state)),
+            d = {
+                "conv": mk((L, B, c.ssm_conv - 1, M.conv_dim(c))),
                 "ssm": mk((L, B, c.ssm_heads, c.ssm_headdim, c.ssm_state), jnp.float32),
             }
-        if c.family == "hybrid":
-            G = c.n_layers // c.attn_every
-            R = c.n_layers - G * c.attn_every
-            conv_dim = c.d_inner + 2 * M.NGROUPS * c.ssm_state
-            d = {
-                "mamba": {
-                    "conv": mk((G * c.attn_every, B, c.ssm_conv - 1, conv_dim)),
-                    "ssm": mk((G * c.attn_every, B, c.ssm_heads, c.ssm_headdim, c.ssm_state), jnp.float32),
-                },
-                "k": mk((G, B, S, K, hd)),
-                "v": mk((G, B, S, K, hd)),
-            }
-            if R:
-                d["extra"] = {
-                    "conv": mk((R, B, c.ssm_conv - 1, conv_dim)),
-                    "ssm": mk((R, B, c.ssm_heads, c.ssm_headdim, c.ssm_state), jnp.float32),
-                }
+            if c.family == "hybrid":    # keys and values of each application
+                A = len(c.hybrid_layer_ids)
+                d["k"] = [mk((B, S, K, hd)) for _ in range(A)]
+                d["v"] = [mk((B, S, K, hd)) for _ in range(A)]
             return d
         L = c.n_layers
         return {"k": mk((L, B, S, K, hd)), "v": mk((L, B, S, K, hd))}
 
     def cache_logical(self, shape: ShapeConfig):
         c = self.cfg
-        if c.family == "ssm":
-            return {"conv": (None, "batch", None, "tp"),
-                    "ssm": (None, "batch", "tp", None, None)}
-        if c.family == "hybrid":
-            d = {
-                "mamba": {"conv": (None, "batch", None, "tp"),
-                          "ssm": (None, "batch", "tp", None, None)},
-                "k": (None, "batch", "seq", None, None),
-                "v": (None, "batch", "seq", None, None),
-            }
-            G = c.n_layers // c.attn_every
-            if c.n_layers - G * c.attn_every:
-                d["extra"] = {"conv": (None, "batch", None, "tp"),
-                              "ssm": (None, "batch", "tp", None, None)}
+        if c.family in ("ssm", "hybrid"):
+            d = {"conv": (None, "batch", None, "tp"),
+                 "ssm": (None, "batch", "tp", None, None)}
+            if c.family == "hybrid":
+                A = len(c.hybrid_layer_ids)
+                d["k"] = [("batch", "seq", None, None)] * A
+                d["v"] = [("batch", "seq", None, None)] * A
             return d
         return {"k": (None, "batch", "seq", None, None),
                 "v": (None, "batch", "seq", None, None)}
@@ -184,7 +164,7 @@ class Model:
         if c.family == "ssm":
             xh, cache = self._ssm_cached(params, x, cache, pos=None)
         elif c.family == "hybrid":
-            xh, cache = H.hybrid_forward_cached(params, x, c, self.shd, positions, cache)
+            xh, cache = H.hybrid_trunk(params, x, c, self.shd, positions, cache)
         else:
             xh, cache = T.forward_with_cache(params, x, c, self.shd, positions, cache)
         logits = T.unembed(params, xh[:, -1:], c, self.shd)
@@ -205,8 +185,8 @@ class Model:
         if c.family == "ssm":
             xh, cache = self._ssm_cached(params, x, cache, pos=pos)
         elif c.family == "hybrid":
-            xh, cache = H.hybrid_forward_cached(params, x, c, self.shd, positions,
-                                                cache, pos=pos)
+            xh, cache = H.hybrid_trunk(params, x, c, self.shd, positions, cache,
+                                       pos=pos)
         else:
             xh, cache = T.forward_with_cache(params, x, c, self.shd, positions,
                                              cache, pos=pos)

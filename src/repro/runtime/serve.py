@@ -63,23 +63,29 @@ class BatchedServer:
         done = np.zeros((B,), bool)
         with telemetry.span("serve.sample"):
             tok = self._sample(logits, serve, key)
+        # Decode step t is dispatched before the token it consumes is read
+        # back, so the device decodes while the host waits, reads and
+        # dispatches: the host's per-token round trip (and its jitter) stays
+        # off the device's path.  On an end token the step already
+        # dispatched is dropped.
         for t in range(serve.max_new_tokens):
+            cur = tok
+            with telemetry.span("serve.decode"):
+                pos = jnp.array(P + t, jnp.int32)
+                if note and t == 0:
+                    telemetry.note_program("serve.decode", self._lower["serve.decode"],
+                                           self.params, cache, cur, pos)
+                logits, cache = self._decode(self.params, cache, cur, pos)
+            with telemetry.span("serve.sample"):
+                key, sub = jax.random.split(key)
+                tok = self._sample(logits, serve, sub)
             with telemetry.span("serve.readback"):
-                host_tok = np.asarray(tok)
+                host_tok = np.asarray(cur)
             outs.append(host_tok)
             if serve.eos_id >= 0:
                 done |= (host_tok.reshape(B, -1)[:, 0] == serve.eos_id)
                 if done.all():
                     break
-            with telemetry.span("serve.decode"):
-                pos = jnp.array(P + t, jnp.int32)
-                if note and t == 0:
-                    telemetry.note_program("serve.decode", self._lower["serve.decode"],
-                                           self.params, cache, tok, pos)
-                logits, cache = self._decode(self.params, cache, tok, pos)
-            with telemetry.span("serve.sample"):
-                key, sub = jax.random.split(key)
-                tok = self._sample(logits, serve, sub)
         return np.stack(outs, axis=1)
 
     def _sample(self, logits, serve: ServeConfig, key):

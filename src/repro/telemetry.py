@@ -38,13 +38,15 @@ TRAIN_STEP = "train"
 #: wait for the loss, metric readback / straggler / guard / logging
 TRAIN_SPANS = ("train.batch", "train.step", "train.sync", "train.host")
 #: spans of ``BatchedServer.generate``: cache init and prefill dispatch once;
-#: per token the readback, the sampling program, and the decode dispatch
+#: per token the decode dispatch, the sampling program, and the readback of
+#: the token the step consumed (one step behind the device)
 SERVE_SPANS = ("serve.prefill", "serve.readback", "serve.sample", "serve.decode")
-#: named scopes: the ops of each model part, the step's accumulation, the
-#: optimizer, and the explicit-DP gradient exchange (pack, codec, collectives,
-#: unpack)
+#: named scopes: the ops of each model part (``adapter``: a hybrid's
+#: per-application LoRA and output linear), the step's accumulation, the
+#: optimizer, and the explicit-DP gradient exchange (pack, codec,
+#: collectives, unpack)
 SCOPES = ("attention", "mlp", "head", "mixer", "grad_accum", "optimizer",
-          "exchange")
+          "exchange", "adapter")
 #: programs noted by ``note_program``
 PROGRAMS = ("train.step", "serve.prefill", "serve.decode")
 #: the paths ``models.layers.attention`` counts in ``ATTENTION_PATHS``: the

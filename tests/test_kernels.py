@@ -15,13 +15,13 @@ from repro.models.layers import blockwise_attention
 RNG = np.random.RandomState(0)
 
 
-def _attn_ref_4d(q, k, v, causal=True):
+def _attn_ref_4d(q, k, v, causal=True, scale=None):
     """ref.attention_ref over (B, S, H, hd), k and v repeated to H heads."""
     b, s, h, hd = q.shape
     g = h // k.shape[2]
     k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
-    out = ref.attention_ref(fold(q), fold(k), fold(v), causal=causal)
+    out = ref.attention_ref(fold(q), fold(k), fold(v), causal=causal, scale=scale)
     return out.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
 
 
@@ -105,6 +105,25 @@ def test_flash_attention_block_shapes(blocks):
     got = _grads(partial(fa.flash_attention, blocks=blocks), q, k, v, ct)
     want = _grads(_attn_ref_4d, q, k, v, ct)
     for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4, rtol=1e-4)
+
+
+def test_flash_attention_zamba2_head_dim():
+    """Head dim 224 (zamba2-7b's), scores scaled by (224 / 2) ** -0.5: values
+    and gradients against the float32 oracle, in float32 at two blocks of 256
+    (the order of sums is all that differs); at S = 3584 the kernel takes
+    blocks of 512."""
+    assert fa.block_sizes(3584, 224).block_q == 512
+    assert fa.block_sizes(3584, 224).block_kv_dkv == 512
+    b, s, h, kh, hd = 1, 512, 2, 2, 224
+    scale = (hd / 2) ** -0.5
+    q, k, v = _qkv(RNG, b, s, h, kh, hd, jnp.float32)
+    fused = partial(fa.flash_attention, blocks=_blocks(256, 256), scale=scale)
+    oracle = partial(_attn_ref_4d, scale=scale)
+    np.testing.assert_allclose(np.asarray(fused(q, k, v)), np.asarray(oracle(q, k, v)),
+                               atol=1e-5, rtol=1e-5)
+    ct = jnp.array(RNG.randn(b, s, h, hd), jnp.float32)
+    for g, w in zip(_grads(fused, q, k, v, ct), _grads(oracle, q, k, v, ct)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4, rtol=1e-4)
 
 

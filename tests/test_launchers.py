@@ -26,10 +26,13 @@ def test_train_launcher_cli(tmp_path):
 
 
 @pytest.mark.slow
-def test_serve_launcher_cli():
-    out = _run_cli(["repro.launch.serve", "--arch", "smollm-135m", "--reduced",
+@pytest.mark.parametrize("arch", [["smollm-135m"],
+                                  # the hybrid's first 2 layers: one application
+                                  ["zamba2-7b", "--layers", "2"]])
+def test_serve_launcher_cli(arch):
+    out = _run_cli(["repro.launch.serve", "--arch", *arch, "--reduced",
                     "--batch", "2", "--prompt-len", "8", "--new-tokens", "2"])
-    assert "tok/s" in out
+    assert "tok/s" in out and "0 compiles" in out
 
 
 @pytest.mark.slow

@@ -133,10 +133,13 @@ def test_long_500k_applicability():
     assert runnable == {"mamba2-2.7b", "zamba2-7b"}
 
 
-def test_ssd_chunked_matches_reference():
+@pytest.mark.parametrize("g", [1, 2])
+def test_ssd_chunked_matches_reference(g):
+    """Chunked SSD against the recurrence, with one group and with two (head
+    h reads group h // (heads / groups))."""
     from repro.models.mamba2 import ssd_chunked, ssd_reference
     rng = np.random.RandomState(0)
-    b, s, h, p, g, n = 2, 32, 4, 8, 1, 16
+    b, s, h, p, n = 2, 32, 4, 8, 16
     x = jnp.array(rng.randn(b, s, h, p), jnp.float32)
     dt = jax.nn.softplus(jnp.array(rng.randn(b, s, h), jnp.float32))
     A = -jnp.exp(jnp.array(rng.randn(h), jnp.float32))
@@ -208,7 +211,8 @@ def test_attention_path_on_the_cpu():
     ({}, {}, "fused"),
     ({}, {"s": 4096}, "fused"),
     ({}, {"s": 200}, "scan"),               # S not a multiple of a block
-    ({}, {"hd": 32}, "scan"),               # head dim not a multiple of 64
+    ({}, {"hd": 32}, "scan"),               # head dim under a lane, not a multiple of 64
+    ({}, {"hd": 224}, "fused"),             # zamba2-7b's head dim
     ({"q_offset": 8}, {}, "scan"),
     ({"causal": False}, {}, "scan"),
 ])

@@ -148,6 +148,19 @@ def test_serve_greedy_deterministic():
     assert a.shape == (2, 4)
 
 
+def test_serve_stops_at_eos():
+    """Rows that all reach the end token stop there; the tokens before it are
+    those of a run without one."""
+    cfg = get_config("smollm-135m").reduced()
+    srv = BatchedServer(cfg, max_seq=48, batch_size=2)
+    prompt = np.random.RandomState(1).randint(0, cfg.vocab, (1, 8)).astype(np.int32)
+    prompts = np.repeat(prompt, 2, axis=0)
+    full = srv.generate(prompts, ServeConfig(max_new_tokens=4))
+    cut = srv.generate(prompts, ServeConfig(max_new_tokens=4, eos_id=int(full[0, 1])))
+    stop = int(np.argmax(full[0] == full[0, 1])) + 1
+    np.testing.assert_array_equal(cut, full[:, :stop])
+
+
 def test_microbatch_indivisible_raises_named_error():
     """An indivisible microbatch split must name the batch size and count
     instead of surfacing an opaque reshape error."""

@@ -226,6 +226,14 @@ def test_serve_spans_per_token(profiled):
         sorted(s[0] for s in profiled["serve"])
 
 
+def test_serve_dispatches_each_step_before_reading_its_token(profiled):
+    """Per token: decode dispatch, sampling, then the readback of the token
+    that step consumed, so the device decodes while the host reads."""
+    names = [s[0] for s in profiled["serve_log"]]
+    assert names == ["serve.prefill", "serve.sample"] + \
+        ["serve.decode", "serve.sample", "serve.readback"] * 3
+
+
 @pytest.mark.parametrize("program, scopes", [
     ("train.step", {"attention", "mlp", "head", "grad_accum", "optimizer"}),
     ("serve.decode", {"mixer", "head"}),

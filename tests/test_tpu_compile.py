@@ -161,3 +161,31 @@ def test_flash_attention_per_shard_compiles_four_chips(topo, compiled_kernels):
     for collective in ("all-reduce", "all-gather", "all-to-all",
                        "collective-permute"):
         assert collective not in text, collective
+
+
+def test_zamba2_prefill_compiles_on_one_chip(one_chip, compiled_kernels, monkeypatch):
+    """zamba2-7b's benchmark cut (24 layers, shared blocks before layers 6,
+    11, 17, 23) prefilling 4 prompts of 3584 into a 3848-position cache on
+    one described v5e: the default attention takes the fused kernel at head
+    dim 224 (one call per application, each under the `attention` scope),
+    and the program's arguments, outputs and temporaries fit the chip's 16 GB."""
+    import dataclasses
+
+    from repro.configs.base import ShapeConfig
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("zamba2-7b"), n_layers=24,
+                              hybrid_layer_ids=(6, 11, 17, 23))
+    model = build_model(cfg)
+    on_chip = lambda t: jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, one_chip), t)
+    params = on_chip(model.abstract_params())
+    cache = on_chip(model.abstract_cache(ShapeConfig("serve", 3848, 4, "decode"), 4))
+    tokens = _spec((4, 3584), jnp.int32, one_chip)
+    telemetry.ATTENTION_PATHS.clear()
+    compiled = jax.jit(model.prefill).lower(params, {"tokens": tokens}, cache).compile()
+    assert dict(telemetry.ATTENTION_PATHS) == {"fused": 4}
+    kernels = _kernel_scopes(compiled.as_text())
+    assert len(kernels) == 4 and set(kernels.values()) == {"attention"}, kernels
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert total < 16e9, total
