@@ -64,9 +64,12 @@ def main(argv=None):
     t0 = time.perf_counter()
     out = server.generate(prompts, sc)
     wall = time.perf_counter() - t0
+    compiles = telemetry.COMPILES.total - built
+    aliased = ", ".join(f"{p} {telemetry.CACHE_ALIASED['serve.' + p]():.2f}"
+                        for p in ("prefill", "decode"))
     print(f"{cfg.name}: {out.shape[0] * out.shape[1] / wall:.1f} tok/s "
           f"(batch {out.shape[0]}, {out.shape[1]} new, {wall:.2f}s, "
-          f"{telemetry.COMPILES.total - built} compiles)")
+          f"{compiles} compiles); cache aliased in place: {aliased}")
     return 0
 
 

@@ -39,7 +39,8 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..configs.base import ModelConfig
-from .layers import apply_rope, attention, decode_attention, mlp, rms_norm
+from .layers import (apply_rope, attention, decode_attention, layer_scan, mlp,
+                     rms_norm)
 from .mamba2 import mamba_block, ssm_param_defs
 from .sharding import Sharder
 
@@ -124,25 +125,18 @@ def _mamba_run(x, t, mp, states, first: int, last: int, cfg: ModelConfig,
                shd: Optional[Sharder], decode: bool):
     """Mamba2 layers first..last-1 over x, T (or None) added to the first
     one's input.  ``states`` (L, B, ...) conv / ssm, or None: decode reads and
-    writes layer l's, prefill writes them."""
+    writes layer l's, prefill writes them, in place (``layer_scan``)."""
 
-    def body(carry, l):
-        x, st = carry
+    def step(x, l, lp, st):
         h = x if t is None else jnp.where(l == first, x + t, x)
-        out, new = mamba_block(h, _index(mp, l), cfg, shd,
-                               _index(st, l) if decode else None)
-        if st is not None:
-            st = jax.tree.map(lambda a, n: jax.lax.dynamic_update_index_in_dim(
-                a, n.astype(a.dtype), l, 0), st, new)
+        out, new = mamba_block(h, lp, cfg, shd, st if decode else None)
         x = x + out
         if shd is not None:
             x = shd.constrain(x, "batch", None, None)
-        return (x, st), None
+        return x, new
 
-    if cfg.remat == "block" and states is None:
-        body = jax.checkpoint(body)
-    (x, states), _ = jax.lax.scan(body, (x, states), jnp.arange(first, last))
-    return x, states
+    return layer_scan(step, x, mp, states, first, last,
+                      remat=cfg.remat == "block" and states is None)
 
 
 def hybrid_trunk(params, x0, cfg: ModelConfig, shd: Optional[Sharder], positions,

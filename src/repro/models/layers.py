@@ -269,6 +269,33 @@ def attention(q, k, v, *, impl: str = "flash", causal: bool = True,
                                context_parallel=False, scale=scale)
 
 
+def layer_scan(step, x, stack, cache, first: int, last: int,
+               remat: bool = False):
+    """Layers first..last-1 as one ``lax.scan`` over their indices.
+
+    ``step(x, l, lp, c) -> (x, new c)`` gets layer l's slice ``lp`` of the
+    stacked parameters ``stack`` and its slice ``c`` of the stacked
+    ``cache`` (arrays (L, ...); None without a cache).  The cache rides in
+    the carry and each layer writes its new slice back in place, so a cache
+    the caller donates is updated with no copy (read as the scan's ``xs`` and
+    returned as its stacked ``ys``, it would be a new array that XLA copies
+    into the donated buffer).  Returns (x, cache)."""
+
+    def body(carry, l):
+        x, c = carry
+        x, new = step(x, l, jax.tree.map(lambda a: a[l], stack),
+                      None if c is None else jax.tree.map(lambda a: a[l], c))
+        if c is not None:
+            c = jax.tree.map(lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                a, n.astype(a.dtype), l, 0), c, new)
+        return (x, c), None
+
+    if remat:
+        body = jax.checkpoint(body)
+    (x, cache), _ = jax.lax.scan(body, (x, cache), jnp.arange(first, last))
+    return x, cache
+
+
 def mlp(x: jnp.ndarray, params: dict, kind: str = "swiglu",
         shd: Optional[Sharder] = None,
         adapter: Optional[dict] = None) -> jnp.ndarray:

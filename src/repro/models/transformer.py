@@ -17,7 +17,8 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from .. import telemetry
-from .layers import apply_rope, attention, decode_attention, mlp, rms_norm
+from .layers import (apply_rope, attention, decode_attention, layer_scan, mlp,
+                     rms_norm)
 from .sharding import Sharder
 
 PARAM_DTYPE = jnp.bfloat16
@@ -259,18 +260,16 @@ def forward(params, x, cfg: ModelConfig, shd: Sharder, positions):
 def forward_with_cache(params, x, cfg: ModelConfig, shd: Sharder, positions,
                        cache, pos=None):
     """Prefill (pos=None) or single-token decode (pos=scalar).  cache:
-    {"k": (L,B,S,K,hd), "v": ...}."""
-    lyr = params["layers"]
+    {"k": (L,B,S,K,hd), "v": ...}, carried through the layers and written in
+    place (``layer_scan``)."""
 
-    def body(carry, xs):
-        h = carry
-        lp, kc, vc = xs
-        h, new_kv, _ = transformer_layer(h, lp, cfg, shd, positions, (kc, vc), pos)
-        return h, new_kv
+    def step(h, l, lp, kv):
+        h, (k, v), _ = transformer_layer(h, lp, cfg, shd, positions,
+                                         (kv["k"], kv["v"]), pos)
+        return h, {"k": k, "v": v}
 
-    x, kvs = jax.lax.scan(body, x, (lyr, cache["k"], cache["v"]))
-    new_cache = {"k": kvs[0], "v": kvs[1]}
-    return rms_norm(x, params["ln_f"]), new_cache
+    x, cache = layer_scan(step, x, params["layers"], cache, 0, cfg.n_layers)
+    return rms_norm(x, params["ln_f"]), cache
 
 
 # ------------------------------------------------------------------- losses

@@ -28,6 +28,17 @@ class ServeConfig:
     seed: int = 0
 
 
+#: the cache entries that are the server's own: the keys and values, which
+#: each program extends in place
+KV = ("k", "v")
+
+
+def split_cache(cache):
+    """(keys and values, recurrent state) of a model's cache."""
+    return ({k: a for k, a in cache.items() if k in KV},
+            {k: a for k, a in cache.items() if k not in KV})
+
+
 class BatchedServer:
     def __init__(self, model_cfg: ModelConfig, max_seq: int, batch_size: int,
                  mesh=None, params=None):
@@ -36,12 +47,35 @@ class BatchedServer:
         self.model = build_model(model_cfg, mesh)
         self.params = params if params is not None else \
             self.model.init(jax.random.PRNGKey(0))
-        self._prefill = jax.jit(self.model.prefill)
-        self._decode = jax.jit(self.model.decode)
-        # kept apart from the two above, which a caller may wrap
-        self._lower = {"serve.prefill": self._prefill.lower,
-                       "serve.decode": self._decode.lower}
+        model = self.model
+
+        def prefill(params, batch, kv, state):
+            return model.prefill(params, batch, {**kv, **state})
+
+        def decode(params, kv, state, tokens, pos):
+            return model.decode(params, {**kv, **state}, tokens, pos)
+
+        # The keys and values (``self._kv``) are donated: each program writes
+        # its new entries into them in place, and the server alone holds
+        # them.  The recurrent state is handed in and out, not donated: a
+        # caller may pass a step's input state on again (the benchmark's
+        # ``decode_state_unchanged`` fault does), so it must stay readable.
+        self._programs = {"serve.prefill": jax.jit(prefill, donate_argnums=2),
+                          "serve.decode": jax.jit(decode, donate_argnums=1)}
+        # kept apart from ``_prefill`` and ``_decode``, which a caller may wrap
+        self._lower = {name: p.lower for name, p in self._programs.items()}
+        self._kv = None
         self._noted = set()     # prompt shapes whose programs are noted
+
+    def _prefill(self, params, batch, state):
+        logits, cache = self._programs["serve.prefill"](params, batch, self._kv, state)
+        self._kv, state = split_cache(cache)
+        return logits, state
+
+    def _decode(self, params, state, tokens, pos):
+        logits, cache = self._programs["serve.decode"](params, self._kv, state, tokens, pos)
+        self._kv, state = split_cache(cache)
+        return logits, state
 
     def generate(self, prompts: np.ndarray, serve: Optional[ServeConfig] = None) -> np.ndarray:
         """prompts: (B, P) int32 (audio: (B, P, nq)).  Returns generated ids
@@ -52,12 +86,13 @@ class BatchedServer:
         note = prompts.shape not in self._noted    # once per program
         self._noted.add(prompts.shape)
         with telemetry.span("serve.prefill"):
-            cache = self.model.init_cache(self.shape, batch_size=B)
+            self._kv, state = split_cache(self.model.init_cache(self.shape, batch_size=B))
             batch = {"tokens": jnp.asarray(prompts)}
             if note:
                 telemetry.note_program("serve.prefill", self._lower["serve.prefill"],
-                                       self.params, batch, cache)
-            logits, cache = self._prefill(self.params, batch, cache)
+                                       self.params, batch, self._kv, state,
+                                       cache_args=(2, 3))
+            logits, state = self._prefill(self.params, batch, state)
         key = jax.random.PRNGKey(serve.seed)
         outs = []
         done = np.zeros((B,), bool)
@@ -74,8 +109,9 @@ class BatchedServer:
                 pos = jnp.array(P + t, jnp.int32)
                 if note and t == 0:
                     telemetry.note_program("serve.decode", self._lower["serve.decode"],
-                                           self.params, cache, cur, pos)
-                logits, cache = self._decode(self.params, cache, cur, pos)
+                                           self.params, self._kv, state, cur, pos,
+                                           cache_args=(1, 2))
+                logits, state = self._decode(self.params, state, cur, pos)
             with telemetry.span("serve.sample"):
                 key, sub = jax.random.split(key)
                 tok = self._sample(logits, serve, sub)

@@ -18,6 +18,11 @@
   names can be read back to their scopes.  ``use_compile_cache`` puts
   ``op_name`` metadata into the persistent cache's key, so that text is the
   one of the executable that ran, also where it came from that cache.
+* ``CACHE_ALIASED``, kept by ``note_program`` for a program that takes a
+  cache: the share of the cache's device bytes that the compiled program
+  aliases to its outputs (``memory_analysis().alias_size_in_bytes``), read
+  when asked for: the share it updates in place (0: every step writes a
+  fresh cache).
 
 Every name the program emits is in the table below; readers import them
 from here and never retype them.
@@ -62,6 +67,9 @@ recording = jax.profiler.TraceAnnotation.is_enabled
 SPANS: collections.deque = collections.deque(maxlen=1 << 20)
 #: program name -> () -> compiled HLO text (``note_program``)
 NOTED: Dict[str, Callable[[], str]] = {}
+#: program name -> () -> share of its cache argument's device bytes that the
+#: compiled program aliases to its outputs (``note_program``)
+CACHE_ALIASED: Dict[str, Callable[[], float]] = {}
 #: attention path (``ATTENTION_PATH_NAMES``) -> times it was traced
 ATTENTION_PATHS: collections.Counter = collections.Counter()
 
@@ -116,12 +124,22 @@ def _spec(x):
                                 sharding=x.sharding if x.committed else None)
 
 
-def note_program(name: str, lower: Callable, *args) -> None:
+def note_program(name: str, lower: Callable, *args,
+                 cache_args: Tuple[int, ...] = ()) -> None:
     """Keep ``lower(*specs).compile().as_text()`` for ``name`` (``lower`` is
     a jitted function's ``.lower``): after the program has run, that compile
-    is the in-memory cache's, the executable that ran."""
+    is the in-memory cache's, the executable that ran.  ``cache_args``: the
+    indices of the arguments that hold the program's cache, whose aliased
+    share ``CACHE_ALIASED[name]`` reads from the same compile."""
     specs = jax.tree.map(_spec, args)
-    NOTED[name] = lambda: lower(*specs).compile().as_text()
+    compiled = lambda: lower(*specs).compile()
+    NOTED[name] = lambda: compiled().as_text()
+    if cache_args:
+        # one device's share: the compiled program is one device's
+        nbytes = sum(x.addressable_shards[0].data.on_device_size_in_bytes()
+                     for i in cache_args for x in jax.tree.leaves(args[i]))
+        CACHE_ALIASED[name] = lambda: \
+            compiled().memory_analysis().alias_size_in_bytes / nbytes
 
 
 @dataclasses.dataclass

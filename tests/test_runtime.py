@@ -138,8 +138,14 @@ def test_prefetch_iterator():
     it.close()
 
 
-def test_serve_greedy_deterministic():
-    cfg = get_config("smollm-135m").reduced()
+SERVED_ARCHS = ["mamba2-2.7b", "zamba2-7b", "smollm-135m"]   # ssm, hybrid, dense
+
+
+@pytest.mark.parametrize("arch", SERVED_ARCHS)
+def test_serve_greedy_deterministic(arch):
+    """Two batches in a row on one server: the second reads no cache buffer
+    that the first handed to a program."""
+    cfg = get_config(arch).reduced()
     srv = BatchedServer(cfg, max_seq=48, batch_size=2)
     prompts = np.random.RandomState(0).randint(0, cfg.vocab, (2, 8)).astype(np.int32)
     a = srv.generate(prompts, ServeConfig(max_new_tokens=4))
@@ -148,10 +154,11 @@ def test_serve_greedy_deterministic():
     assert a.shape == (2, 4)
 
 
-def test_serve_stops_at_eos():
+@pytest.mark.parametrize("arch", SERVED_ARCHS)
+def test_serve_stops_at_eos(arch):
     """Rows that all reach the end token stop there; the tokens before it are
     those of a run without one."""
-    cfg = get_config("smollm-135m").reduced()
+    cfg = get_config(arch).reduced()
     srv = BatchedServer(cfg, max_seq=48, batch_size=2)
     prompt = np.random.RandomState(1).randint(0, cfg.vocab, (1, 8)).astype(np.int32)
     prompts = np.repeat(prompt, 2, axis=0)
@@ -159,6 +166,32 @@ def test_serve_stops_at_eos():
     cut = srv.generate(prompts, ServeConfig(max_new_tokens=4, eos_id=int(full[0, 1])))
     stop = int(np.argmax(full[0] == full[0, 1])) + 1
     np.testing.assert_array_equal(cut, full[:, :stop])
+
+
+def _undonated_greedy(model, params, prompts, max_seq, n):
+    """Greedy tokens of a plain loop of undonated prefill and decode calls."""
+    prefill, decode = jax.jit(model.prefill), jax.jit(model.decode)
+    B, P = prompts.shape
+    cache = model.init_cache(ShapeConfig("serve", max_seq, B, "decode"), B)
+    logits, cache = prefill(params, {"tokens": jnp.asarray(prompts)}, cache)
+    out = []
+    for t in range(n):
+        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        out.append(np.asarray(tok))
+        logits, cache = decode(params, cache, tok, jnp.array(P + t, jnp.int32))
+    return np.stack(out, axis=1)
+
+
+@pytest.mark.parametrize("arch", SERVED_ARCHS)
+def test_donated_serve_matches_an_undonated_loop(arch):
+    """The server's programs take the cache donated and write it in place;
+    its tokens are those of undonated prefill and decode calls."""
+    cfg = get_config(arch).reduced()
+    srv = BatchedServer(cfg, max_seq=32, batch_size=2)
+    prompts = np.random.RandomState(2).randint(0, cfg.vocab, (2, 8)).astype(np.int32)
+    np.testing.assert_array_equal(
+        srv.generate(prompts, ServeConfig(max_new_tokens=6)),
+        _undonated_greedy(srv.model, srv.params, prompts, 32, 6))
 
 
 def test_microbatch_indivisible_raises_named_error():

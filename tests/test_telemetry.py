@@ -244,6 +244,34 @@ def test_compiled_ops_carry_their_scopes(profiled, program, scopes):
     assert scopes <= found, (program, found)
 
 
+@pytest.mark.parametrize("arch, kv_share", [("smollm-135m", 1.0),
+                                           ("zamba2-7b", None)])
+def test_cache_aliased_reads_the_donated_programs(monkeypatch, arch, kv_share):
+    """A served model's prefill and decode alias the keys and values, the
+    part of the cache the server donates, to their outputs: all of a dense
+    model's cache, the hybrid's but its recurrent state; an undonated jit of
+    the same decode aliases none of it."""
+    from repro.configs.base import ShapeConfig
+    from repro.runtime.serve import BatchedServer, ServeConfig, split_cache
+
+    monkeypatch.setattr(tm, "NOTED", {})
+    monkeypatch.setattr(tm, "CACHE_ALIASED", {})
+    server = BatchedServer(get_config(arch).reduced(), max_seq=24, batch_size=2)
+    prompts = np.random.RandomState(0).randint(0, server.cfg.vocab, (2, 8))
+    server.generate(prompts.astype(np.int32), ServeConfig(max_new_tokens=2))
+    model = server.model
+    cache = model.init_cache(ShapeConfig("serve", 24, 2, "decode"), 2)
+    nbytes = lambda t: sum(a.nbytes for a in jax.tree.leaves(t))
+    share = nbytes(split_cache(cache)[0]) / nbytes(cache)
+    assert kv_share is None and 0 < share < 1 or share == kv_share
+    assert {k: f() for k, f in tm.CACHE_ALIASED.items()} == \
+        {"serve.prefill": share, "serve.decode": share}
+    tm.note_program("plain.decode", jax.jit(model.decode).lower, server.params,
+                    cache, jnp.zeros((2,), jnp.int32), jnp.array(8, jnp.int32),
+                    cache_args=(1,))
+    assert tm.CACHE_ALIASED["plain.decode"]() == 0.0
+
+
 EXCHANGE_SCOPE = r"""
 import jax
 from jax.sharding import AxisType

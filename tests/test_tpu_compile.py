@@ -10,6 +10,7 @@ resolves to.  The topology is described inside a fixture, so only the worker
 that runs this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -163,19 +164,21 @@ def test_flash_attention_per_shard_compiles_four_chips(topo, compiled_kernels):
         assert collective not in text, collective
 
 
+def _zamba2_cut():
+    import dataclasses
+    return dataclasses.replace(get_config("zamba2-7b"), n_layers=24,
+                               hybrid_layer_ids=(6, 11, 17, 23))
+
+
 def test_zamba2_prefill_compiles_on_one_chip(one_chip, compiled_kernels, monkeypatch):
     """zamba2-7b's benchmark cut (24 layers, shared blocks before layers 6,
     11, 17, 23) prefilling 4 prompts of 3584 into a 3848-position cache on
     one described v5e: the default attention takes the fused kernel at head
     dim 224 (one call per application, each under the `attention` scope),
     and the program's arguments, outputs and temporaries fit the chip's 16 GB."""
-    import dataclasses
-
     from repro.configs.base import ShapeConfig
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = dataclasses.replace(get_config("zamba2-7b"), n_layers=24,
-                              hybrid_layer_ids=(6, 11, 17, 23))
-    model = build_model(cfg)
+    model = build_model(_zamba2_cut())
     on_chip = lambda t: jax.tree.map(
         lambda a: _spec(a.shape, a.dtype, one_chip), t)
     params = on_chip(model.abstract_params())
@@ -189,3 +192,77 @@ def test_zamba2_prefill_compiles_on_one_chip(one_chip, compiled_kernels, monkeyp
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     assert total < 16e9, total
+
+
+#: the served programs at full width: config, batch, cache length, prompt
+#: length (the zamba2 and mamba2 cells' shapes; smollm-135m, served by no
+#: cell, stands for the dense family)
+SERVED = {"zamba2-7b": (_zamba2_cut, 4, 3848, 3584),
+          "mamba2-2.7b": (lambda: get_config("mamba2-2.7b"), 16, 776, 512),
+          "smollm-135m": (lambda: get_config("smollm-135m"), 16, 2048, 1792)}
+
+
+def _served_program(arch, kind, one_chip):
+    """``BatchedServer``'s own program ``kind`` (``serve.prefill`` or
+    ``serve.decode``) for ``arch``, compiled for one described v5e at full
+    width.  Returns (compiled, the cache's keys and values, its recurrent
+    state, the keys' and values' bytes on the chip)."""
+    from repro.configs.base import ShapeConfig
+    from repro.runtime.serve import BatchedServer, split_cache
+
+    make, B, S, P = SERVED[arch]
+    on_chip = lambda t: jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, one_chip), t)
+    model = build_model(make())
+    srv = BatchedServer(model.cfg, max_seq=S, batch_size=B,
+                        params=on_chip(model.abstract_params()))
+    kv, state = split_cache(on_chip(
+        model.abstract_cache(ShapeConfig("serve", S, B, "decode"), B)))
+    if kind == "serve.prefill":
+        args = (srv.params, {"tokens": _spec((B, P), jnp.int32, one_chip)}, kv, state)
+    else:
+        args = (srv.params, kv, state, _spec((B,), jnp.int32, one_chip),
+                _spec((), jnp.int32, one_chip))
+    nbytes = jax.jit(lambda c: c).lower(kv).compile() \
+        .memory_analysis().argument_size_in_bytes
+    return srv._lower[kind](*args).compile(), kv, state, nbytes
+
+
+COPY_RE = re.compile(r"%([\w.-]+) = (\w+\[([\d,]*)\]\{[^}]*\}) copy\(%([\w.-]+)\)")
+
+
+def _whole_copies(text, leaves):
+    """The copies in a compiled module of an array shaped as one of
+    ``leaves`` or as one layer's slice of one, that keep its layout: a second
+    buffer of the same array (a copy that changes the layout is a relayout)."""
+    shapes = {a.shape for a in leaves} | {a.shape[1:] for a in leaves}
+    defs = dict(re.findall(r"%([\w.-]+) = (\w+\[[\d,]*\]\{[^}]*\})", text))
+    return [f"{name} = {res} copy({arg})"
+            for name, res, dims, arg in COPY_RE.findall(text)
+            if tuple(int(d) for d in dims.split(",") if d) in shapes
+            and defs.get(arg) == res]
+
+
+@pytest.mark.parametrize("arch, kind, temp_limit, state_copies", [
+    ("zamba2-7b", "serve.decode", 1.2e9, 1),    # its weight prefetches count
+    ("mamba2-2.7b", "serve.decode", 64e6, 0),
+    ("smollm-135m", "serve.decode", 64e6, 0),
+    ("zamba2-7b", "serve.prefill", None, 0),
+])
+def test_served_cache_is_updated_in_place(one_chip, compiled_kernels, monkeypatch,
+                                          arch, kind, temp_limit, state_copies):
+    """The server's programs take the keys and values donated and every
+    family's layer loop carries them, so the compiled program aliases all of
+    them to its output and holds no second copy of any key or value array.
+    The recurrent state is not donated: the ssm family reads it as a scan's
+    input and writes a fresh one, with no copy; the hybrid's decode, which
+    carries it, copies it once."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, kv, state, nbytes = _served_program(arch, kind, one_chip)
+    text = compiled.as_text()
+    assert _whole_copies(text, jax.tree.leaves(kv)) == []
+    assert len(_whole_copies(text, jax.tree.leaves(state))) == state_copies
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == nbytes, (mem.alias_size_in_bytes, nbytes)
+    if temp_limit is not None:
+        assert mem.temp_size_in_bytes < temp_limit, mem.temp_size_in_bytes
